@@ -1,6 +1,7 @@
 """Mesh-sharded serve tier benchmark (DESIGN.md S3): the LM merged-group
 decode scenario served from a ParamStore carrying a ``MeshPlacement`` over a
-forced 2x4 CPU mesh, vs the identical single-device store.
+(devices/4, 4) ``("data", "model")`` mesh built from the devices present,
+vs the identical single-device store.
 
     XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         PYTHONPATH=src python -m benchmarks.shard_serve [--json]
@@ -24,9 +25,9 @@ Lanes (emitted as ``BENCH_shard``):
    sharded admission (replicated trunk per shard, private suffixes on their
    home shards) must serve every request to completion.
 
-With fewer than 8 devices the sharded lanes degrade gracefully (rows note
-the skip; ``derived.sharded=false``) so ``benchmarks.run`` stays green on a
-plain host — the forced-8 CI lane is where the gates bind.
+It needs a device count that four divides (the bank has four members, one
+per ``model`` shard) and exits non-zero otherwise; the CPU lane forces eight
+host devices, a v5e host has four chips.
 """
 import argparse
 import json
@@ -42,7 +43,7 @@ DECODE_KW = dict(page_size=PAGE_SIZE, num_pages=64, max_slots=8, max_len=16,
 PROMPT_LEN = 7
 MAX_NEW = 5
 N_PER_MODEL = 2
-MESH_SHAPE = (2, 4)  # ("data", "model") -> 4 bank shards
+BANK_SHARDS = 4  # the merged (A, B, D, E) group's bank: a member per shard
 
 
 def serve_rules(mesh):
@@ -63,7 +64,8 @@ def _mk_placement():
 
     from repro.distributed.partitioning import MeshPlacement
 
-    mesh = jax.make_mesh(MESH_SHAPE, ("data", "model"))
+    n = jax.device_count()
+    mesh = jax.make_mesh((n // BANK_SHARDS, BANK_SHARDS), ("data", "model"))
     return MeshPlacement(serve_rules(mesh), bank_axis="model")
 
 
@@ -123,10 +125,15 @@ def _bitwise(a: dict, b: dict) -> bool:
 
 def _serve_pair(adapter, cfg, plan, placement, mode: str):
     """(unsharded stats+map, sharded stats+map) under one kernel mode.
-    Fresh engines per mode: jit caches are per-engine and ``default_mode``
-    is read at trace time, so the switch needs no process restart."""
+    ``default_mode`` is read at trace time and JAX reuses a function's
+    trace across ``jax.jit`` wrappers, so the switch drops every cached
+    trace first; without that, a later mode would replay the earlier mode's
+    traces."""
+    import jax
+
     prev = os.environ.get("REPRO_KERNEL_MODE")
     os.environ["REPRO_KERNEL_MODE"] = mode
+    jax.clear_caches()
     try:
         base = _engine(adapter, cfg, plan)
         base_stats = base.serve_decode(_requests(cfg, list(base.programs)),
@@ -141,6 +148,7 @@ def _serve_pair(adapter, cfg, plan, placement, mode: str):
             os.environ.pop("REPRO_KERNEL_MODE", None)
         else:
             os.environ["REPRO_KERNEL_MODE"] = prev
+        jax.clear_caches()
     return (base_stats, base_map), (shard_stats, shard_map_), shard
 
 
@@ -212,13 +220,11 @@ def run(quiet: bool = False) -> dict:
     from benchmarks.lm_merging import plan_variants
     from repro.models.registry import get_adapter
 
-    need = MESH_SHAPE[0] * MESH_SHAPE[1]
-    if jax.device_count() < need:
-        return emit("BENCH_shard", [
-            {"lane": "skipped", "reason": f"{jax.device_count()} devices < "
-             f"{need} (run under XLA_FLAGS="
-             "--xla_force_host_platform_device_count=8)"}],
-            {"sharded": False, "devices": jax.device_count()}, quiet=quiet)
+    n = jax.device_count()
+    if n % BANK_SHARDS:
+        raise SystemExit(
+            f"shard_serve needs a multiple of {BANK_SHARDS} devices, found "
+            f"{n} (on CPU: XLA_FLAGS=--xla_force_host_platform_device_count=8)")
 
     adapter = get_adapter("dense")
     cfg = adapter.default_config()
@@ -246,7 +252,7 @@ def run(quiet: bool = False) -> dict:
     derived = {
         "sharded": True,
         "devices": jax.device_count(),
-        "mesh": "x".join(map(str, MESH_SHAPE)),
+        "mesh": "x".join(map(str, placement.mesh.devices.shape)),
         "n_shards": placement.n_shards,
         "bank_sharded_over_model_axis": any(
             shard_eng._bank_sharded) if shard_eng else False,
@@ -267,8 +273,6 @@ def main(argv=None):
     if args.json:
         print(json.dumps(out, indent=2, default=str))
     d = out["derived"]
-    if not d.get("sharded"):
-        return  # degraded host: gates bind only in the forced-8 lane
     checks = (
         d["bitwise_ref"] and d["bitwise_interpret"]
         and d["epoch_bumps_ok"]

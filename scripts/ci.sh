@@ -188,8 +188,8 @@ PY
 # mesh-sharded serve tier (DESIGN.md S3), forced-8-device CPU lane: the
 # ParamStore shard round-trip tests skip on a 1-device host, so this lane
 # forces a 2x4 host-platform mesh (the flag lives HERE, not in test code —
-# conftest mandate) and then runs the shard_serve benchmark whose gates
-# bind only when 8 devices are visible
+# conftest mandate) and then runs the shard_serve benchmark, which builds a
+# (devices/4, 4) mesh and fails on a device count four does not divide
 XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \
   python -m pytest -q tests/test_sharded_store.py
 XLA_FLAGS="--xla_force_host_platform_device_count=8${XLA_FLAGS:+ $XLA_FLAGS}" \

@@ -18,8 +18,10 @@ any CPU-only CI runner:
   contract: scans and the suffix-bank GEMM surface f32 outputs even from
   bf16 inputs, attention returns the query dtype (f32 accumulation stays
   internal), page_gather preserves the pool dtype.
-* **guards** — shape combinations that violate a kernel's block-divisibility
-  asserts must RAISE at trace time, not miscompute.
+* **guards** — shape combinations that violate a kernel's asserts (head
+  grouping, contraction dims, scan block divisibility) must RAISE at trace
+  time, not miscompute.  Attention and the bank GEMM pad a ragged sequence
+  or row count instead; those shapes are ordinary cases.
 
 ``run_contracts`` takes the table/cases/modes as injectable arguments so the
 unit tests can feed it a deliberately skewed fake op and watch it fail.
@@ -98,10 +100,14 @@ def build_contracts() -> dict:
                 Case("mha_windowed", flash(1, 32, 2, 2, 16),
                      lambda dt: _sds((1, 32, 2, 16), dt),
                      static=dict(causal=True, window=8)),
+                # a block not dividing S: the wrapper pads S and slices back
+                Case("ragged_seq_padded", flash(2, 16, 4, 2, 8),
+                     lambda dt: _sds((2, 16, 4, 8), dt),
+                     static=dict(block_q=12)),
             ),
             guards=(
-                GuardCase("block_q_not_dividing_S", flash(2, 16, 4, 2, 8),
-                          static=dict(block_q=12)),
+                GuardCase("kv_heads_not_dividing_q_heads",
+                          flash(2, 16, 4, 3, 8)),
             ),
         ),
         "decode_attention": OpContract(
@@ -110,10 +116,14 @@ def build_contracts() -> dict:
                      lambda dt: _sds((2, 4, 8), dt)),
                 Case("mha_cache", decode(3, 64, 2, 2, 16),
                      lambda dt: _sds((3, 2, 16), dt)),
+                # a block not dividing Smax: the wrapper pads, lengths mask
+                Case("ragged_cache_padded", decode(2, 32, 4, 2, 8),
+                     lambda dt: _sds((2, 4, 8), dt),
+                     static=dict(block_k=12)),
             ),
             guards=(
-                GuardCase("block_k_not_dividing_Smax", decode(2, 32, 4, 2, 8),
-                          static=dict(block_k=12)),
+                GuardCase("kv_heads_not_dividing_q_heads",
+                          decode(2, 32, 4, 3, 8)),
             ),
         ),
         "rg_lru_scan": OpContract(
@@ -177,12 +187,14 @@ def build_contracts() -> dict:
                      lambda dt: dict(x=_sds((16, 8), dt),
                                      w=_sds((3, 8, 16), dt)),
                      lambda dt: _sds((3, 16, 16), jnp.float32)),
+                # a block not dividing M = batch * seq: rows padded, sliced
+                Case("ragged_rows_padded",
+                     lambda dt: dict(x=_sds((3, 16, 8), dt),
+                                     w=_sds((3, 8, 16), dt)),
+                     lambda dt: _sds((3, 16, 16), jnp.float32),
+                     static=dict(block_m=12)),
             ),
             guards=(
-                GuardCase("block_m_not_dividing_M",
-                          lambda dt: dict(x=_sds((3, 16, 8), dt),
-                                          w=_sds((3, 8, 16), dt)),
-                          static=dict(block_m=12)),
                 GuardCase("contraction_mismatch",
                           lambda dt: dict(x=_sds((3, 16, 9), dt),
                                           w=_sds((3, 8, 16), dt))),

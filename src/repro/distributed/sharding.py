@@ -120,10 +120,19 @@ def shard_bank_fn(fn, mesh: Mesh, axis: str):
 
     Caller guarantees N divides the axis extent (the divisibility guard in
     ``MeshPlacement.bank_sharding``)."""
-    from jax.experimental.shard_map import shard_map
-
     # in_specs are pytree prefixes: P(axis) shards every bank leaf's leading
-    # dim; P() replicates the whole feats tree.  check_rep=False: the kernel
-    # body (pallas_call in interpret mode) has no replication rule.
-    return shard_map(fn, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(axis), check_rep=False)
+    # dim; P() replicates the whole feats tree.  check_vma=False: the kernel
+    # body (a pallas_call) has no varying-manual-axes rule.
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(axis), check_vma=False)
+
+
+def replicate_fn(fn, mesh: Mesh):
+    """Wrap ``fn`` to run whole on every device of ``mesh`` via
+    ``shard_map``: every operand and result replicated, the body traced
+    against full shapes.  A program jitted over a multi-device mesh must
+    hand-partition any Pallas TPU (Mosaic) kernel it holds — XLA cannot
+    partition the custom call — and the serve tier's replicated trunk is
+    exactly this case: each device computes it on its own replica."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)

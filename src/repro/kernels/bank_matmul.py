@@ -71,7 +71,7 @@ def _bank_bias_kernel(x_ref, w_ref, b_ref, o_ref, acc_ref, *, num_k_blocks: int)
 
     @pl.when(ki == num_k_blocks - 1)
     def _emit():
-        o_ref[0, :, :] = acc_ref[...] + b_ref[0].astype(jnp.float32)
+        o_ref[0, :, :] = acc_ref[...] + b_ref[0].astype(jnp.float32)  # (1, bf)
 
 
 @functools.partial(
@@ -87,7 +87,13 @@ def bank_matmul(
     *,
     interpret: bool,
 ) -> jax.Array:
-    """Returns (N, M, F) float32 with out[n] = x[n] @ w[n] (+ b[n])."""
+    """Returns (N, M, F) float32 with out[n] = x[n] @ w[n] (+ b[n]).
+
+    Rows beyond a multiple of ``block_m`` are zero-padded and sliced off, so
+    any M = batch * seq the serving path emits is accepted.  The bias rides
+    as an (N, 1, F) view: its block's last two dims are then (1, block_f),
+    which the TPU lowering accepts where an (N, F) bias's (1, block_f) block
+    is refused."""
     N, K, F = w.shape
     broadcast = x.ndim == 2
     M = x.shape[0] if broadcast else x.shape[1]
@@ -97,9 +103,13 @@ def bank_matmul(
     block_m = min(block_m, M)
     block_f = min(block_f, F)
     block_k = min(block_k, K)
-    assert M % block_m == 0 and F % block_f == 0 and K % block_k == 0, (
-        (M, F, K), (block_m, block_f, block_k))
-    nm, nf, nk = M // block_m, F // block_f, K // block_k
+    assert F % block_f == 0 and K % block_k == 0, (
+        (F, K), (block_f, block_k))
+    Mp = -(-M // block_m) * block_m
+    if Mp > M:
+        pad = [(0, Mp - M), (0, 0)] if broadcast else [(0, 0), (0, Mp - M), (0, 0)]
+        x = jnp.pad(x, pad)
+    nm, nf, nk = Mp // block_m, F // block_f, K // block_k
 
     if broadcast:
         x_spec = pl.BlockSpec((block_m, block_k), lambda n, mi, fi, ki: (mi, ki))
@@ -117,15 +127,16 @@ def bank_matmul(
         assert b.shape == (N, F), (b.shape, (N, F))
         kernel = functools.partial(_bank_bias_kernel, num_k_blocks=nk)
         in_specs = [x_spec, w_spec,
-                    pl.BlockSpec((1, block_f), lambda n, mi, fi, ki: (n, fi))]
-        operands = (x, w, b)
+                    pl.BlockSpec((1, 1, block_f), lambda n, mi, fi, ki: (n, 0, fi))]
+        operands = (x, w, b.reshape(N, 1, F))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(N, nm, nf, nk),
         in_specs=in_specs,
         out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((N, M, F), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((N, Mp, F), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)],
         interpret=interpret,
     )(*operands)
+    return out[:, :M] if Mp > M else out

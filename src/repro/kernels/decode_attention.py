@@ -8,6 +8,13 @@ axis is the sequential innermost axis carrying online-softmax state in VMEM.
 VMEM per instance: q (G, D) + k,v (block_k, D) + acc (G, D) + m/l — tiny;
 block_k = 256 keeps the HBM reads wide.  Length masking is positional
 (no gather): a block whose start >= length is skipped entirely.
+
+The wrapper hands the kernel a head-major (B, Hkv, Smax, D) view of the
+cache and writes a (B, Hkv, G, D) output, so every block's last two dims
+are (block_k, D) or (G, D): the TPU lowering needs them divisible by
+(8, 128) or whole, which per-head blocks over the (H, D) minor dims of the
+cache layout are not (and G is 1 for MHA).  A cache longer than a block
+but not a multiple of it is zero-padded; the length mask hides the pad.
 """
 from __future__ import annotations
 
@@ -44,8 +51,8 @@ def _decode_kernel(
     @pl.when(k_start < length)
     def _compute():
         q = q_ref[0, 0, :, :].astype(jnp.float32)  # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # (G, bk)
@@ -66,7 +73,7 @@ def _decode_kernel(
     def _emit():
         l = l_ref[:, 0]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, :] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -87,9 +94,14 @@ def decode_attention(
     assert Hq % Hkv == 0
     grp = Hq // Hkv
     block_k = min(block_k, Smax)
-    assert Smax % block_k == 0
-    nk = Smax // block_k
+    Sp = -(-Smax // block_k) * block_k
+    nk = Sp // block_k
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
+
+    def head_major(x):  # (B, Smax, Hkv, D) -> (B, Hkv, Sp, D)
+        x = x.transpose(0, 2, 1, 3)
+        return (jnp.pad(x, [(0, 0), (0, 0), (0, Sp - Smax), (0, 0)])
+                if Sp > Smax else x)
 
     qg = q.reshape(B, Hkv, grp, D)
     kernel = functools.partial(
@@ -102,17 +114,17 @@ def decode_attention(
             grid=(B, Hkv, nk),
             in_specs=[
                 pl.BlockSpec((1, 1, grp, D), lambda b, h, ki, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, block_k, 1, D), lambda b, h, ki, lens: (b, ki, h, 0)),
-                pl.BlockSpec((1, block_k, 1, D), lambda b, h, ki, lens: (b, ki, h, 0)),
+                pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, lens: (b, h, ki, 0)),
+                pl.BlockSpec((1, 1, block_k, D), lambda b, h, ki, lens: (b, h, ki, 0)),
             ],
-            out_specs=pl.BlockSpec((1, grp, D), lambda b, h, ki, lens: (b, h, 0)),
+            out_specs=pl.BlockSpec((1, 1, grp, D), lambda b, h, ki, lens: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((grp, D), jnp.float32),
                 pltpu.VMEM((grp, 128), jnp.float32),
                 pltpu.VMEM((grp, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hkv * grp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, grp, D), q.dtype),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), qg, head_major(k_cache), head_major(v_cache))
     return out.reshape(B, Hq, D)

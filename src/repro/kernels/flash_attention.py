@@ -5,6 +5,13 @@ executed sequentially on TPU, so the online-softmax state (m, l, acc) lives
 in VMEM scratch and carries across kv steps; the output block is emitted at
 the final kv step.
 
+The wrapper takes the model's (B, S, H, D) layout and hands the kernel a
+head-major (B, H, S, D) view, so every block's last two dims are
+(block, D): the TPU lowering needs them divisible by (8, 128) or whole, and
+a per-head block over the (H, D) minor dims of the model layout is neither.
+A sequence longer than a block but not a multiple of it is zero-padded to
+one; padded keys are masked, padded query rows are sliced off.
+
 VMEM working set per program instance:
     q block   (block_q, D)        bf16/f32
     k,v block (block_k, D)  x 2
@@ -35,7 +42,7 @@ def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref,  # blocks
     acc_ref, m_ref, l_ref,  # VMEM scratch
     *, block_q: int, block_k: int, scale: float, causal: bool,
-    window: Optional[int], num_kv_blocks: int, grp: int,
+    window: Optional[int], num_kv_blocks: int, grp: int, kv_len: int,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -52,6 +59,8 @@ def _flash_kernel(
     # block-level relevance: causal => k_start <= q_end; window => block not
     # entirely older than the window
     relevant = k_start <= q_start + block_q - 1 if causal else True
+    if num_kv_blocks * block_k > kv_len:  # padded tail: skip all-pad blocks
+        relevant = jnp.logical_and(relevant, k_start < kv_len)
     if window is not None:
         relevant = jnp.logical_and(
             relevant, (q_start - (k_start + block_k - 1)) < window
@@ -59,9 +68,9 @@ def _flash_kernel(
 
     @pl.when(relevant if not isinstance(relevant, bool) else relevant)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -74,6 +83,8 @@ def _flash_kernel(
             mask &= kp <= qp
         if window is not None:
             mask &= (qp - kp) < window
+        if num_kv_blocks * block_k > kv_len:
+            mask &= kp < kv_len
         s = jnp.where(mask, s, NEG_INF)
 
         m_prev = m_ref[:, 0]  # (bq,)
@@ -91,7 +102,7 @@ def _flash_kernel(
     def _emit():
         l = l_ref[:, 0]
         safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / safe[:, None]).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -116,28 +127,34 @@ def flash_attention(
     grp = Hq // Hkv
     block_q = min(block_q, S)
     block_k = min(block_k, S)
-    assert S % block_q == 0 and S % block_k == 0, (S, block_q, block_k)
-    nq, nk = S // block_q, S // block_k
+    step = int(np.lcm(block_q, block_k))
+    Sp = -(-S // step) * step
+    nq, nk = Sp // block_q, Sp // block_k
     scale = float(1.0 / np.sqrt(D)) if scale is None else float(scale)
+
+    def head_major(x):  # (B, S, H, D) -> (B, H, Sp, D)
+        x = x.transpose(0, 2, 1, 3)
+        return jnp.pad(x, [(0, 0), (0, 0), (0, Sp - S), (0, 0)]) if Sp > S else x
 
     kernel = functools.partial(
         _flash_kernel, block_q=block_q, block_k=block_k, scale=scale,
-        causal=causal, window=window, num_kv_blocks=nk, grp=grp,
+        causal=causal, window=window, num_kv_blocks=nk, grp=grp, kv_len=S,
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, Hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // grp, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, qi, ki: (b, ki, h // grp, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // grp, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, qi, ki: (b, h // grp, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sp, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, D), jnp.float32),   # acc
             pltpu.VMEM((block_q, 128), jnp.float32),  # m (lane-padded)
             pltpu.VMEM((block_q, 128), jnp.float32),  # l (lane-padded)
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(head_major(q), head_major(k), head_major(v))
+    return out[:, :, :S].transpose(0, 2, 1, 3)

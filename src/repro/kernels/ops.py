@@ -6,7 +6,9 @@
                         custom calls carry no XLA cost model, DESIGN.md A5)
 
 Default resolves from the REPRO_KERNEL_MODE env var, falling back to "ref"
-on CPU hosts and "kernel" when a TPU is present.
+on CPU hosts and "kernel" when a TPU is present.  On a TPU the mode is
+"kernel": ``chip_smoke.py`` refuses to run when the env var overrides it,
+since a chip run on the oracles or the interpreter measures nothing real.
 """
 from __future__ import annotations
 

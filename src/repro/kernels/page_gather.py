@@ -10,7 +10,11 @@ TPU-idiomatic implementation: the page table is a *scalar-prefetch* operand
 (pltpu.PrefetchScalarGridSpec) so the index arrives before the grid step and
 the BlockSpec ``index_map`` itself selects the pool row — the gather becomes
 pure block DMA, no vector compute at all, exactly like paged-attention KV
-lookups.  Grid (N,); VMEM per step = one (1, page_size) tile.
+lookups.  Grid (N,); VMEM per step = one page.
+
+Each page travels as a (rows, lanes) tile — (page/128, 128) when the page
+is lane-aligned, else (1, page) — so a block's last two dims are the whole
+page: the TPU lowering refuses a (1, page) block over a (P, page) pool.
 """
 from __future__ import annotations
 
@@ -37,17 +41,19 @@ def page_gather(
     """Returns out (N, page) with out[i] = pool[page_table[i]]."""
     P, page = pool.shape
     (N,) = page_table.shape
+    tile = (page // 128, 128) if page % 128 == 0 else (1, page)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _gather_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(N,),
             in_specs=[
-                pl.BlockSpec((1, page), lambda i, table: (table[i], 0)),
+                pl.BlockSpec((1, *tile), lambda i, table: (table[i], 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, page), lambda i, table: (i, 0)),
+            out_specs=pl.BlockSpec((1, *tile), lambda i, table: (i, 0, 0)),
         ),
-        out_shape=jax.ShapeDtypeStruct((N, page), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((N, *tile), pool.dtype),
         interpret=interpret,
-    )(page_table.astype(jnp.int32), pool)
+    )(page_table.astype(jnp.int32), pool.reshape(P, *tile))
+    return out.reshape(N, page)

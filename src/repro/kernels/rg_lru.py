@@ -10,6 +10,10 @@ sublane-major traversal, one VPU multiply-add per step.
 
 VMEM per instance: a,b,y tiles (chunk, block_d) x 3 + h (1, block_d).
 chunk=256, block_d=512, f32: ~1.6 MB.
+
+The (B, d) initial and final states ride as (B, 1, d) views, so their
+blocks' last two dims are (1, block_d): the TPU lowering refuses a
+(1, block_d) block over a (B, d) array whenever B > 1.
 """
 from __future__ import annotations
 
@@ -27,7 +31,7 @@ def _rg_lru_kernel(a_ref, b_ref, h0_ref, y_ref, hlast_ref, h_ref, *, chunk: int,
 
     @pl.when(ci == 0)
     def _init():
-        h_ref[...] = h0_ref[0, :].astype(jnp.float32)[None, :]
+        h_ref[...] = h0_ref[0].astype(jnp.float32)
 
     def step(t, h):
         at = a_ref[0, t, :].astype(jnp.float32)
@@ -41,7 +45,7 @@ def _rg_lru_kernel(a_ref, b_ref, h0_ref, y_ref, hlast_ref, h_ref, *, chunk: int,
 
     @pl.when(ci == num_chunks - 1)
     def _emit():
-        hlast_ref[0, :] = h.astype(hlast_ref.dtype)
+        hlast_ref[0] = h[None, :].astype(hlast_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
@@ -68,17 +72,17 @@ def rg_lru_scan(
         in_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b_, di, ci: (b_, ci, di)),
             pl.BlockSpec((1, chunk, block_d), lambda b_, di, ci: (b_, ci, di)),
-            pl.BlockSpec((1, block_d), lambda b_, di, ci: (b_, di)),
+            pl.BlockSpec((1, 1, block_d), lambda b_, di, ci: (b_, 0, di)),
         ],
         out_specs=[
             pl.BlockSpec((1, chunk, block_d), lambda b_, di, ci: (b_, ci, di)),
-            pl.BlockSpec((1, block_d), lambda b_, di, ci: (b_, di)),
+            pl.BlockSpec((1, 1, block_d), lambda b_, di, ci: (b_, 0, di)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, S, d), jnp.float32),
-            jax.ShapeDtypeStruct((B, d), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
-    )(a, b, h0)
-    return y, h_last
+    )(a, b, h0.reshape(B, 1, d))
+    return y, h_last.reshape(B, d)

@@ -21,6 +21,10 @@ def main(argv=None):
     ap.add_argument("--json", action="store_true")
     args = ap.parse_args(argv)
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     from repro.serving.profiler import profile_workload
     from repro.serving.scheduler import Scheduler
     from repro.serving.simulator import simulate

@@ -294,10 +294,17 @@ class StreamingDecoder:
         return pool
 
     def _fn(self, kind: str, fn: Callable, *extra):
+        """Jitted ``fn``, cached per (kind, callable, extra).  Under a mesh
+        placement the engine places it first: the ``"bank"`` fan-out
+        (``extra`` = member count) shard_maps over the bank axis — bitwise
+        identical, scaled over devices — and everything else runs
+        replicated."""
         key = (kind, MergeAwareEngine._callable_key(fn), *extra)
         jitted = self._compiled.get(key)
         if jitted is None:
-            jitted = self._compiled[key] = jax.jit(fn)
+            placed = (self.engine.maybe_shard_bank(fn, *extra) if kind == "bank"
+                      else self.engine.maybe_replicate(fn))
+            jitted = self._compiled[key] = jax.jit(placed)
         return jitted
 
     def submit(self, req: DecodeRequest) -> int:
@@ -456,12 +463,7 @@ class StreamingDecoder:
                         and dec.bank_head is not None)
             if bankable:
                 bank_params = self.engine._bank_params(group)
-                # under a mesh placement the fan-out is shard_map'd over the
-                # bank axis (engine-cached wrapper, stable identity for the
-                # jit cache) — bitwise identical, scaled over devices
-                bank_fn = self.engine.maybe_shard_bank(dec.bank_head,
-                                                       len(group))
-                out = self._fn("bank", bank_fn,
+                out = self._fn("bank", dec.bank_head,
                                len(group))(bank_params, hidden)
                 self.stats["bank_dispatches"] += 1
                 member_row = {iid: n for n, iid in enumerate(group)}
@@ -522,10 +524,8 @@ class StreamingDecoder:
                         params, kv, *args)
                     if (self.engine._group_bankable(tuple(group))
                             and dec.bank_head is not None):
-                        bank_fn = self.engine.maybe_shard_bank(
-                            dec.bank_head, len(group))
                         jax.block_until_ready(
-                            self._fn("bank", bank_fn, len(group))(
+                            self._fn("bank", dec.bank_head, len(group))(
                                 self.engine._bank_params(group), hidden))
                     for iid in group:
                         jax.block_until_ready(
@@ -591,18 +591,18 @@ class StreamingDecoder:
         }
 
 
-def verify_bitwise(decoder: StreamingDecoder, sample: Optional[int] = None,
-                   require_logits: bool = True) -> bool:
-    """Replay completed requests through the family's UNPAGED ``decode_step``
-    (B=1, contiguous cache with the same Smax = max_len) and compare the
-    generated tokens — and, when the decoder recorded them, every generated
-    token's logits — bitwise.  This is the ref-mode oracle contract: paged +
-    continuous-batched + bank-fanned decode must be indistinguishable from
-    the seed's sequential decode.  Only valid for completions produced under
-    the store's CURRENT bindings (skip after a mid-stream swap)."""
+def replay_logits(decoder: StreamingDecoder,
+                  sample: Optional[int] = None) -> list:
+    """Teacher-forced replay of completed requests through the family's
+    UNPAGED ``decode_step`` (B=1, contiguous cache with the same Smax =
+    max_len): feed each request's prompt and then its own generated tokens,
+    one token per step.  Returns ``[(completion, rows)]`` with ``rows`` the
+    (n_generated, V) replayed logits of every generated token.  Only valid
+    for completions produced under the store's CURRENT bindings (skip after
+    a mid-stream swap)."""
     engine = decoder.engine
     jitted: dict = {}
-    ok = True
+    out = []
     comps = decoder.completions if sample is None else \
         decoder.completions[:sample]
     for c in comps:
@@ -614,22 +614,35 @@ def verify_bitwise(decoder: StreamingDecoder, sample: Optional[int] = None,
         params = engine.store.materialize_cached(prog.model_id)
         cache = dec.init_cache(1, decoder.max_len)
         prompt = [int(t) for t in c.request.prompt]
-        feed = prompt + c.tokens[:-1]
-        gen_i = 0
-        for i, tok in enumerate(feed):
+        rows = []
+        for i, tok in enumerate(prompt + c.tokens[:-1]):
             logits, cache = step(params, cache,
                                  jnp.full((1, 1), tok, jnp.int32))
             if i >= len(prompt) - 1:  # this step emits a generated token
-                row = np.asarray(logits)[0, 0]
-                if int(np.argmax(row)) != c.tokens[gen_i]:
-                    ok = False
-                if c.logits is not None:
-                    if not np.array_equal(row, c.logits[gen_i]):
-                        ok = False
-                elif require_logits:
-                    raise ValueError("verify_bitwise needs record_logits=True"
-                                     " for logits comparison")
-                gen_i += 1
-        if gen_i != len(c.tokens):
+                rows.append(np.asarray(logits)[0, 0])
+        out.append((c, np.stack(rows) if rows else np.zeros((0, 0))))
+    return out
+
+
+def verify_bitwise(decoder: StreamingDecoder, sample: Optional[int] = None,
+                   require_logits: bool = True) -> bool:
+    """Compare the generated tokens — and, when the decoder recorded them,
+    every generated token's logits — with :func:`replay_logits` bitwise.
+    This is the ref-mode oracle contract: paged + continuous-batched +
+    bank-fanned decode must be indistinguishable from the seed's sequential
+    decode."""
+    comps = decoder.completions if sample is None else \
+        decoder.completions[:sample]
+    if require_logits and any(c.logits is None for c in comps):
+        raise ValueError("verify_bitwise needs record_logits=True"
+                         " for logits comparison")
+    ok = True
+    for c, rows in replay_logits(decoder, sample):
+        if len(rows) != len(c.tokens):
             ok = False
+            continue
+        if [int(np.argmax(r)) for r in rows] != list(c.tokens):
+            ok = False
+        if c.logits is not None:
+            ok &= all(np.array_equal(r, g) for r, g in zip(rows, c.logits))
     return ok

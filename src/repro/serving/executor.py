@@ -426,12 +426,15 @@ class MergeAwareEngine:
         missing = set(self.programs) ^ {i.instance_id for i in instances}
         if missing:
             raise ValueError(f"programs/instances mismatch: {missing}")
-        self._fwd = {p.instance_id: jax.jit(p.forward) for p in programs}
+        self._replicated: dict = {}  # (callable, mesh) -> shard_map'd fn
+        self._fwd = {p.instance_id: jax.jit(self.maybe_replicate(p.forward))
+                     for p in programs}
         # prefixes compile lazily, cached per (callable identity, binding
         # signature): instances whose prefix weights are one physical buffer
         # set share ONE jitted prefix instead of tracing per instance
         self._prefix_compiled: dict = {}
-        self._suffix = {p.instance_id: (jax.jit(p.suffix) if p.suffix else None)
+        self._suffix = {p.instance_id: (jax.jit(self.maybe_replicate(p.suffix))
+                                        if p.suffix else None)
                         for p in programs}
         self.dma = AsyncDMA(dma_gbps, simulate=simulate_dma, clock=clock)
         self.buckets = tuple(sorted(buckets))
@@ -491,7 +494,7 @@ class MergeAwareEngine:
         key = (self._callable_key(p.prefix), self._binding_sig(iid))
         fn = self._prefix_compiled.get(key)
         if fn is None:
-            fn = jax.jit(p.prefix)
+            fn = jax.jit(self.maybe_replicate(p.prefix))
             self._prefix_compiled[key] = fn
             self.stats["prefix_jits"] += 1
         return fn
@@ -533,9 +536,10 @@ class MergeAwareEngine:
         so outputs stay bitwise identical to the unsharded dispatch while
         the grid (and Pallas BlockSpecs) become shard-local.  Cached per
         (callable, N, mesh, axis) so repeat callers (and the streaming
-        decoder's jit cache) see a stable function identity."""
+        decoder's jit cache) see a stable function identity.  A bank the
+        shards do not divide runs replicated (:meth:`maybe_replicate`)."""
         if not self._bank_sharding_active(n_bank):
-            return fn
+            return self.maybe_replicate(fn)
         from repro.distributed.sharding import shard_bank_fn
 
         pl = self.store.placement
@@ -544,6 +548,24 @@ class MergeAwareEngine:
         if wrapped is None:
             wrapped = shard_bank_fn(fn, pl.mesh, pl.bank_axis)
             self._bank_sharded[key] = wrapped
+        return wrapped
+
+    def maybe_replicate(self, fn):
+        """``fn`` itself on one device; under a multi-device mesh placement,
+        ``fn`` shard_map'd with every operand replicated, so each device
+        runs it whole on its replica (``sharding.replicate_fn``).  Pallas
+        TPU kernels cannot sit in an auto-partitioned multi-device program,
+        so every jitted serve callable goes through here.  Cached per
+        (callable, mesh) for a stable function identity."""
+        pl = self.store.placement
+        if pl is None or pl.mesh.size == 1:
+            return fn
+        from repro.distributed.sharding import replicate_fn
+
+        key = (self._callable_key(fn), pl.mesh)
+        wrapped = self._replicated.get(key)
+        if wrapped is None:
+            wrapped = self._replicated[key] = replicate_fn(fn, pl.mesh)
         return wrapped
 
     def _bank_fn(self, group: list):

@@ -161,3 +161,32 @@ def test_griffin_ring_buffer_long_decode(rng):
     full = G.forward(cfg, p, toks)
     np.testing.assert_allclose(np.asarray(outs[-1]), np.asarray(full[:, -1]),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("variant", [
+    {},
+    dict(norm="rmsnorm", tie_embeddings=True, n_kv_heads=2, qkv_bias=True,
+         qk_norm=True),
+    dict(norm="nonparam_ln", window=5, gated_ffn=False, logit_softcap=30.0,
+         rotary_pct=0.25),
+], ids=["stablelm_like", "tied_gqa_qknorm", "windowed_softcap"])
+def test_f32_reference_matches_dense_forward(variant, rng):
+    """``models.reference`` (the chip run's oracle, written independently of
+    the model code) agrees with ``transformer.forward`` in float32."""
+    from repro.models import reference
+    from repro.models import transformer as T
+
+    kw = dict(variant)
+    cfg = T.DenseLMConfig(
+        n_layers=3, d_model=64, n_heads=4, n_kv_heads=kw.pop("n_kv_heads", 4),
+        head_dim=16, d_ff=128, vocab_size=200, scan_layers=False, **kw)
+    k1, k2 = jax.random.split(rng)
+    params = jax.tree.map(  # move norms and biases off their init values
+        lambda a: a + 0.1 * jax.random.normal(k1, a.shape, a.dtype),
+        T.init(cfg, rng))
+    toks = jax.random.randint(k2, (2, 12), 0, cfg.vocab_size)
+    with jax.default_matmul_precision("highest"):
+        want = T.forward(cfg, params, toks)
+    got = reference.dense_lm_logits(cfg, params, toks)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-4)
