@@ -138,5 +138,6 @@ def bank_matmul(
         out_shape=jax.ShapeDtypeStruct((N, Mp, F), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)],
         interpret=interpret,
+        name="bank_matmul",
     )(*operands)
     return out[:, :M] if Mp > M else out

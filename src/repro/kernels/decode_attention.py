@@ -126,5 +126,6 @@ def decode_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, grp, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qg, head_major(k_cache), head_major(v_cache))
     return out.reshape(B, Hq, D)

@@ -156,5 +156,6 @@ def flash_attention(
             pltpu.VMEM((block_q, 128), jnp.float32),  # l (lane-padded)
         ],
         interpret=interpret,
+        name="flash_attention",
     )(head_major(q), head_major(k), head_major(v))
     return out[:, :, :S].transpose(0, 2, 1, 3)

@@ -96,5 +96,6 @@ def mamba_scan(
         ],
         scratch_shapes=[pltpu.VMEM((block_di, n), jnp.float32)],
         interpret=interpret,
+        name="mamba_scan",
     )(dt, dtx, Bmat, Cmat, A, h0)
     return y, h_last

@@ -55,5 +55,6 @@ def page_gather(
         ),
         out_shape=jax.ShapeDtypeStruct((N, *tile), pool.dtype),
         interpret=interpret,
+        name="page_gather",
     )(page_table.astype(jnp.int32), pool.reshape(P, *tile))
     return out.reshape(N, page)

@@ -84,5 +84,6 @@ def rg_lru_scan(
         ],
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
+        name="rg_lru_scan",
     )(a, b, h0.reshape(B, 1, d))
     return y, h_last.reshape(B, d)

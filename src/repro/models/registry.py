@@ -469,8 +469,11 @@ class DenseLMAdapter(_TokenLMAdapter):
         ep = self.eval_params(cfg)
         paths = transformer.trunk_paths(ep)
 
+        # named scopes label the trunk's and the bank's device operations
+        # in a profiler trace
         def prefix(params, x, _cfg=cfg):
-            return transformer.trunk(_cfg, params, x)
+            with jax.named_scope("trunk"):
+                return transformer.trunk(_cfg, params, x)
 
         def suffix(params, feats, _cfg=cfg):
             return transformer.head(_cfg, params, feats)
@@ -482,7 +485,8 @@ class DenseLMAdapter(_TokenLMAdapter):
             return PrefixSplit(prefix, suffix, paths)
 
         def bank_suffix(bank_params, feats, _cfg=cfg):
-            return transformer.bank_head(_cfg, bank_params, feats)
+            with jax.named_scope("bank"):
+                return transformer.bank_head(_cfg, bank_params, feats)
 
         return PrefixSplit(prefix, suffix, paths,
                            suffix_paths=transformer.head_paths(ep),

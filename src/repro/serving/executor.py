@@ -784,59 +784,75 @@ class MergeAwareEngine:
         vs one per member otherwise); ``suffix_runs`` keeps counting
         logical member-head executions."""
         mbs = deadline_microbatches(reqs, self.buckets)
-        shared = len(group) > 1
-        bankable = shared and self._group_bankable(tuple(group))
+        bankable = len(group) > 1 and self._group_bankable(tuple(group))
         for mb in mbs:
             self.stats["microbatches"] += 1
-            batch, n = pad_stack([r.payload for r in mb.requests], mb.bucket)
             banked = bankable and len(
                 {r.instance_id for r in mb.requests}) > 1
-            if banked:
-                lead = group[0]
-                feats = self._prefix_fn(lead)(self._params(lead), batch)
-                self.stats["prefix_runs"] += 1
-                bank_out = self._bank_fn(group)(self._bank_params(group), feats)
-                self.stats["suffix_runs"] += len(group)
-                self.stats["suffix_dispatches"] += 1
-                jax.block_until_ready(bank_out)
-                slot = {iid: i for i, iid in enumerate(group)}
-                done = self.clock() - t0
-                for j, r in enumerate(mb.requests):
-                    self.completions.append(
-                        Completion(r, bank_out[slot[r.instance_id], j], done))
-                continue
-            rows_by_iid: dict = {}
-            for j, r in enumerate(mb.requests):
-                rows_by_iid.setdefault(r.instance_id, []).append(j)
-            if shared:
-                lead = group[0]
-                feats = self._prefix_fn(lead)(self._params(lead), batch)
-                self.stats["prefix_runs"] += 1
-                outs, pos = {}, {}
-                for iid, idx in rows_by_iid.items():
-                    if len(idx) == mb.bucket:
-                        sub = feats  # whole batch belongs to this instance
+            # profiler spans: four stages tile each micro-batch, so the
+            # device's idle time can be charged to the stage the host was in
+            with jax.profiler.TraceAnnotation(
+                    "engine.microbatch", rows=len(mb.requests),
+                    bucket=mb.bucket, banked=int(banked)):
+                with jax.profiler.TraceAnnotation("engine.pad"):
+                    batch, _ = pad_stack([r.payload for r in mb.requests],
+                                         mb.bucket)
+                with jax.profiler.TraceAnnotation("engine.dispatch"):
+                    if banked:
+                        outs, row_of = self._dispatch_bank(group, batch)
                     else:
-                        # fan out only this instance's rows, padded back onto
-                        # the bucket ladder so suffix shapes stay bounded
-                        sb = next(b for b in self.buckets if len(idx) <= b)
-                        take = idx + [idx[-1]] * (sb - len(idx))
-                        sub = feats[jnp.asarray(take)]
-                    outs[iid] = self._suffix[iid](self._params(iid), sub)
-                    pos[iid] = {g: k for k, g in enumerate(idx)}
-                    self.stats["suffix_runs"] += 1
-                    self.stats["suffix_dispatches"] += 1
-            else:
-                (iid,) = group
-                outs = {iid: self._fwd[iid](self._params(iid), batch)}
-                pos = {iid: {j: j for j in range(len(mb.requests))}}
-                self.stats["forward_runs"] += 1
-            for o in outs.values():
-                jax.block_until_ready(o)
-            done = self.clock() - t0
-            for j, r in enumerate(mb.requests):
-                row = pos[r.instance_id][j]
-                self.completions.append(Completion(r, outs[r.instance_id][row], done))
+                        outs, row_of = self._dispatch_members(group, batch, mb)
+                with jax.profiler.TraceAnnotation("engine.wait"):
+                    jax.block_until_ready(outs)
+                with jax.profiler.TraceAnnotation("engine.complete"):
+                    done = self.clock() - t0
+                    for j, r in enumerate(mb.requests):
+                        self.completions.append(Completion(r, row_of(j, r), done))
+
+    def _dispatch_bank(self, group: list, batch):
+        """Enqueue the prefix once and every member's head in ONE bank
+        dispatch.  Returns the output and ``row_of(j, request)``, which
+        slices request ``j``'s result out of its (member, row) cell."""
+        lead = group[0]
+        feats = self._prefix_fn(lead)(self._params(lead), batch)
+        self.stats["prefix_runs"] += 1
+        bank_out = self._bank_fn(group)(self._bank_params(group), feats)
+        self.stats["suffix_runs"] += len(group)
+        self.stats["suffix_dispatches"] += 1
+        slot = {iid: i for i, iid in enumerate(group)}
+        return bank_out, lambda j, r: bank_out[slot[r.instance_id], j]
+
+    def _dispatch_members(self, group: list, batch, mb):
+        """Enqueue a shared group's prefix and one head per member present
+        (over that member's rows only), or a singleton's whole forward.
+        Returns ``{instance: output}`` and ``row_of(j, request)``."""
+        rows_by_iid: dict = {}
+        for j, r in enumerate(mb.requests):
+            rows_by_iid.setdefault(r.instance_id, []).append(j)
+        if len(group) > 1:
+            lead = group[0]
+            feats = self._prefix_fn(lead)(self._params(lead), batch)
+            self.stats["prefix_runs"] += 1
+            outs, pos = {}, {}
+            for iid, idx in rows_by_iid.items():
+                if len(idx) == mb.bucket:
+                    sub = feats  # whole batch belongs to this instance
+                else:
+                    # fan out only this instance's rows, padded back onto
+                    # the bucket ladder so suffix shapes stay bounded
+                    sb = next(b for b in self.buckets if len(idx) <= b)
+                    take = idx + [idx[-1]] * (sb - len(idx))
+                    sub = feats[jnp.asarray(take)]
+                outs[iid] = self._suffix[iid](self._params(iid), sub)
+                pos[iid] = {g: k for k, g in enumerate(idx)}
+                self.stats["suffix_runs"] += 1
+                self.stats["suffix_dispatches"] += 1
+        else:
+            (iid,) = group
+            outs = {iid: self._fwd[iid](self._params(iid), batch)}
+            pos = {iid: {j: j for j in range(len(mb.requests))}}
+            self.stats["forward_runs"] += 1
+        return outs, lambda j, r: outs[r.instance_id][pos[r.instance_id][j]]
 
     def _warmup(self, payload) -> None:
         """Pre-compile every (group, bucket) shape before the SLA clock
@@ -881,7 +897,13 @@ class MergeAwareEngine:
 
     def serve(self, horizon_s: float, warmup: Any = None, drain: bool = True) -> dict:
         """Serve until the horizon (or until the queues are drained, with
-        ``drain=True``).  Returns stats including cache/prefetch health."""
+        ``drain=True``).  Returns stats including cache/prefetch health.
+
+        Records profiler spans, free while no profiler runs: ``engine.serve``
+        over the loop, ``engine.poll`` over each empty-queue sleep, and per
+        micro-batch ``engine.microbatch`` (``rows``, ``bucket``, ``banked``)
+        tiled by ``engine.pad``, ``engine.dispatch``, ``engine.wait`` and
+        ``engine.complete``."""
         if warmup is not None:
             self._warmup(warmup)
         # per-call accounting: every counter below is reported as the delta
@@ -892,76 +914,79 @@ class MergeAwareEngine:
         skipped_before = self.skipped
         stall_before, hidden_before = self.dma.stall_s, self.dma.hidden_s
         epoch_start = self.store.epoch
-        t0 = self.clock()
-        gi = 0
-        empty_streak = 0
-        while self.clock() - t0 < horizon_s:
-            groups = self.prefix_groups()  # re-plan if an epoch moved
-            now = self.clock() - t0
-            self._drop_expired(now)
-            if not any(self.queues.values()):
-                if drain:
-                    break
-                self.stats["idle_sleeps"] += 1
-                time.sleep(self.idle_sleep_s)
-                continue
-            group = groups[gi % len(groups)]
-            nxt = groups[(gi + 1) % len(groups)]
-            gi += 1
-            reqs = []
-            for iid in group:
-                q = self.queues[iid]
-                while q:
-                    reqs.append(q.popleft())
-            if not reqs:
-                empty_streak += 1
-                if empty_streak >= len(groups):
-                    self.stats["idle_sleeps"] += 1
-                    time.sleep(self.idle_sleep_s)
-                    empty_streak = 0
-                continue
+        with jax.profiler.TraceAnnotation("engine.serve"):
+            t0 = self.clock()
+            gi = 0
             empty_streak = 0
-            max_batch = min(len(reqs), self.buckets[-1])
-            loaded = 0
-            shard_bytes: dict = {}
-            for iid in group:
-                r = self.scheduler.load(iid, max_batch)
-                loaded += r["loaded_bytes"]
-                for s, b in r["loaded_bytes_by_shard"].items():
-                    shard_bytes[s] = shard_bytes.get(s, 0) + b
-            self.dma.wait(tuple(group), loaded)
-            self.dma.account(shard_bytes)
-            # prefetch the NEXT group's incremental bytes; the transfer's
-            # clock runs while this group computes (§3.2 pipelining, made
-            # real).  Sized by peek (pre-eviction estimate).
-            if tuple(nxt) != tuple(group):
-                pre = sum(self.scheduler.peek_load_bytes(iid) for iid in nxt)
-                self.dma.start(tuple(nxt), pre)
-            self._run_group(group, reqs, t0)
-        new = self.completions[done_before:]
-        met = sum(1 for c in new if c.met_sla)
-        skipped = self.skipped - skipped_before
-        total = len(new) + skipped
-        lookups = self.stats["param_lookups"] - stats_before["param_lookups"]
-        rebuilds = sum(self.store.materializations.get(m, 0) - mat_before.get(m, 0)
-                       for m in self.store.materializations)
-        last = max((c.finished_s for c in new), default=0.0)
-        return {
-            "completed": len(new),
-            "met_sla": met,
-            "skipped": skipped,
-            "sla_fraction": met / max(total, 1),
-            "elapsed_s": last,
-            "requests_per_s": len(new) / max(last, 1e-9),
-            "cache_hit_rate": 1.0 - rebuilds / max(lookups, 1),
-            "materializations": rebuilds,
-            "binding_epochs": self.store.epoch - epoch_start + 1,
-            "dma_stall_s": self.dma.stall_s - stall_before,
-            "dma_hidden_s": self.dma.hidden_s - hidden_before,
-            "dma_bytes_by_shard": dict(self.dma.bytes_by_shard),
-            # lifetime count (compiles usually happen in warmup, so the
-            # per-call delta under-reports): distinct compiled prefixes —
-            # a 4-member shared group contributes 1, not 4
-            "prefix_jits_total": self.stats["prefix_jits"],
-            **{k: v - stats_before[k] for k, v in self.stats.items()},
-        }
+            while self.clock() - t0 < horizon_s:
+                groups = self.prefix_groups()  # re-plan if an epoch moved
+                now = self.clock() - t0
+                self._drop_expired(now)
+                if not any(self.queues.values()):
+                    if drain:
+                        break
+                    self.stats["idle_sleeps"] += 1
+                    with jax.profiler.TraceAnnotation("engine.poll"):
+                        time.sleep(self.idle_sleep_s)
+                    continue
+                group = groups[gi % len(groups)]
+                nxt = groups[(gi + 1) % len(groups)]
+                gi += 1
+                reqs = []
+                for iid in group:
+                    q = self.queues[iid]
+                    while q:
+                        reqs.append(q.popleft())
+                if not reqs:
+                    empty_streak += 1
+                    if empty_streak >= len(groups):
+                        self.stats["idle_sleeps"] += 1
+                        with jax.profiler.TraceAnnotation("engine.poll"):
+                            time.sleep(self.idle_sleep_s)
+                        empty_streak = 0
+                    continue
+                empty_streak = 0
+                max_batch = min(len(reqs), self.buckets[-1])
+                loaded = 0
+                shard_bytes: dict = {}
+                for iid in group:
+                    r = self.scheduler.load(iid, max_batch)
+                    loaded += r["loaded_bytes"]
+                    for s, b in r["loaded_bytes_by_shard"].items():
+                        shard_bytes[s] = shard_bytes.get(s, 0) + b
+                self.dma.wait(tuple(group), loaded)
+                self.dma.account(shard_bytes)
+                # prefetch the NEXT group's incremental bytes; the transfer's
+                # clock runs while this group computes (§3.2 pipelining, made
+                # real).  Sized by peek (pre-eviction estimate).
+                if tuple(nxt) != tuple(group):
+                    pre = sum(self.scheduler.peek_load_bytes(iid) for iid in nxt)
+                    self.dma.start(tuple(nxt), pre)
+                self._run_group(group, reqs, t0)
+            new = self.completions[done_before:]
+            met = sum(1 for c in new if c.met_sla)
+            skipped = self.skipped - skipped_before
+            total = len(new) + skipped
+            lookups = self.stats["param_lookups"] - stats_before["param_lookups"]
+            rebuilds = sum(self.store.materializations.get(m, 0) - mat_before.get(m, 0)
+                           for m in self.store.materializations)
+            last = max((c.finished_s for c in new), default=0.0)
+            return {
+                "completed": len(new),
+                "met_sla": met,
+                "skipped": skipped,
+                "sla_fraction": met / max(total, 1),
+                "elapsed_s": last,
+                "requests_per_s": len(new) / max(last, 1e-9),
+                "cache_hit_rate": 1.0 - rebuilds / max(lookups, 1),
+                "materializations": rebuilds,
+                "binding_epochs": self.store.epoch - epoch_start + 1,
+                "dma_stall_s": self.dma.stall_s - stall_before,
+                "dma_hidden_s": self.dma.hidden_s - hidden_before,
+                "dma_bytes_by_shard": dict(self.dma.bytes_by_shard),
+                # lifetime count (compiles usually happen in warmup, so the
+                # per-call delta under-reports): distinct compiled prefixes —
+                # a 4-member shared group contributes 1, not 4
+                "prefix_jits_total": self.stats["prefix_jits"],
+                **{k: v - stats_before[k] for k, v in self.stats.items()},
+            }

@@ -15,6 +15,7 @@ hold the TPU library), and the persistent compilation cache is off around
 the compiles: a program compiled for a described chip cannot be read back.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,10 +100,25 @@ def test_every_op_has_a_compile_case():
     assert {op for op, _, _ in CASES.values()} == set(ops.OP_TABLE)
 
 
+def compiled_text(case: str, sharding) -> str:
+    op, shapes, kw = CASES[case]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding) for s, dt in shapes]
+    fn = functools.partial(ops.OP_TABLE[op].dispatch, mode="kernel", **kw)
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
-    op, shapes, kw = CASES[case]
-    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip) for s, dt in shapes]
-    fn = functools.partial(ops.OP_TABLE[op].dispatch, mode="kernel", **kw)
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    assert "tpu_custom_call" in compiled_text(case, one_chip)
+
+
+@pytest.mark.parametrize("op", sorted(ops.OP_TABLE))
+def test_kernel_call_is_named_for_its_op(op, one_chip, no_compile_cache):
+    """The device trace names a kernel by its HLO instruction, and the
+    roofline readers match that name from its start (``^bank_matmul``,
+    ``^flash_attention``): each Pallas call carries its op's name."""
+    case = min(c for c, (o, _, _) in CASES.items() if o == op)
+    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.-]+) = .*"
+                       r'custom_call_target="tpu_custom_call"',
+                       compiled_text(case, one_chip), re.M)
+    assert any(name.startswith(op) for name in calls), calls
