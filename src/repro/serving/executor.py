@@ -808,6 +808,9 @@ class MergeAwareEngine:
                     done = self.clock() - t0
                     for j, r in enumerate(mb.requests):
                         self.completions.append(Completion(r, row_of(j, r), done))
+                # the completions hold their rows as slices: free this
+                # micro-batch's outputs before the next dispatch allocates
+                del outs, row_of
 
     def _dispatch_bank(self, group: list, batch):
         """Enqueue the prefix once and every member's head in ONE bank

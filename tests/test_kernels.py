@@ -6,7 +6,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref as R
-from repro.kernels.bank_matmul import bank_matmul
+from repro.kernels.bank_matmul import VMEM_BUDGET, _pick_blocks, bank_matmul
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.mamba_scan import mamba_scan
@@ -189,6 +189,9 @@ def test_dispatch_counters_track_trace_time_dispatches(rng):
     (3, 8, 32, 64, 8, 32, 64),      # serving-head scale, single block
     (2, 16, 64, 128, 8, 32, 128),   # multi-block m and k
     (4, 8, 128, 96, 8, 64, 32),     # multi-block k and f
+    (3, 16, 64, 256, None, None, None),  # blocks picked from the shapes
+    (2, 13, 64, 256, None, None, None),  # ragged M, one whole-M block
+    (2, 13, 64, 256, 8, 32, 128),   # ragged M padded to a block multiple
 ])
 @pytest.mark.parametrize("broadcast_x", [False, True])
 @pytest.mark.parametrize("bias", [False, True])
@@ -203,6 +206,72 @@ def test_bank_matmul_sweep(dtype, N, M, K, F, bm, bk, bf, broadcast_x, bias, rng
     assert out.shape == (N, M, F) and out.dtype == jnp.float32
     tol = TOL if dtype == jnp.float32 else dict(rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **tol)
+
+
+@pytest.mark.parametrize("block_k", [None, 64])  # one k block, and split k
+@pytest.mark.parametrize("bias", [False, True])
+def test_bank_matmul_bf16_operands_match_f32_upcast(block_k, bias, rng):
+    """bf16 tiles go to the dot as they are: bf16 x bf16 products are exact
+    in f32, so the result is the f32 contraction of the upcast operands up
+    to f32 summation order."""
+    ks = jax.random.split(rng, 3)
+    x = jax.random.normal(ks[0], (3, 24, 256), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (3, 256, 384), jnp.bfloat16)
+    b = jax.random.normal(ks[2], (3, 384), jnp.bfloat16) if bias else None
+    out = bank_matmul(x, w, b, block_k=block_k, interpret=True)
+    up = lambda a: None if a is None else a.astype(jnp.float32)
+    ref = R.bank_matmul_ref(up(x), up(w), up(b))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-4)
+
+
+def _grid_steps(N, M, K, F, blk):
+    return (N * (blk.rows // blk.block_m) * (F // blk.block_f)
+            * (K // blk.block_k))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_bank_block_rule_at_the_zoo_head(dtype):
+    """StableLM-2-1.6B's four heads (M 4 x 128, K 2048, F 100352): one m
+    and one k block, a few hundred steps, and a VMEM estimate inside both
+    the budget and the limit passed to the compiler, in bf16 and f32."""
+    N, M, K, F = 4, 512, 2048, 100352
+    blk = _pick_blocks(M, K, F, dtype, dtype)
+    assert (blk.block_m, blk.rows, blk.block_k) == (M, M, K)
+    assert F % blk.block_f == 0 and blk.block_f % 128 == 0
+    assert _grid_steps(N, M, K, F, blk) <= 1024
+    assert blk.vmem_bytes <= VMEM_BUDGET
+    assert blk.vmem_bytes < blk.vmem_limit
+
+
+@pytest.mark.parametrize("M,most_rows", [
+    (520, 528),     # ragged: no padding past the next multiple of 16
+    (4, 16),        # a decode batch: one whole block
+    (5000, 5000 + 5 * 16),  # above MAX_BLOCK_M: equal blocks, a sublane each
+])
+def test_bank_block_rule_pads_at_most_a_sublane_a_block(M, most_rows):
+    blk = _pick_blocks(M, 2048, 100352, jnp.bfloat16, jnp.bfloat16,
+                      jnp.bfloat16)
+    assert M <= blk.rows <= most_rows and blk.rows % blk.block_m == 0
+    assert blk.block_m == M or blk.block_m % 16 == 0
+    assert blk.vmem_bytes <= VMEM_BUDGET and blk.vmem_bytes < blk.vmem_limit
+
+
+@pytest.mark.parametrize("N,M,K,F", [(3, 8, 32, 64), (4, 8, 128, 96),
+                                     (2, 16, 64, 128)])
+def test_bank_block_rule_takes_whole_small_dims(N, M, K, F):
+    blk = _pick_blocks(M, K, F, jnp.float32, jnp.float32, jnp.float32)
+    assert (blk.block_m, blk.block_k, blk.block_f, blk.rows) == (M, K, F, M)
+    assert _grid_steps(N, M, K, F, blk) == N
+
+
+def test_bank_block_rule_splits_k_only_when_it_cannot_fit():
+    """A contraction too wide for the budget gets the accumulating path,
+    with a lane-aligned k block that divides K."""
+    K = 65536
+    blk = _pick_blocks(512, K, 100352, jnp.float32, jnp.float32)
+    assert blk.block_k < K and K % blk.block_k == 0 and blk.block_k % 128 == 0
+    assert blk.vmem_bytes <= VMEM_BUDGET and blk.vmem_bytes < blk.vmem_limit
 
 
 def test_bank_matmul_ref_is_bitwise_per_member(rng):

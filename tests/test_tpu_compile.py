@@ -53,9 +53,11 @@ CASES = {
         [((2, B * S, DM), BF16), ((2, DM, V), BF16), ((2, V), BF16)], {}),
     "bank_matmul/decode_head": (  # one token per row, broadcast features
         "bank_matmul", [((B, DM), BF16), ((2, DM, V), BF16)], {}),
-    "bank_matmul/ragged_rows_bias": (  # M=520: padded to 640 internally
+    "bank_matmul/ragged_rows_bias": (  # M=520: one whole-M block, no pad
         "bank_matmul",
         [((2, 520, DM), BF16), ((2, DM, V), BF16), ((2, V), BF16)], {}),
+    "bank_matmul/zoo4_head": (  # four variants' heads: blocks above 16 MiB
+        "bank_matmul", [((4, B * S, DM), BF16), ((4, DM, V), BF16)], {}),
     "mamba_scan/falcon_mamba": (  # d_inner 8192, d_state 16, chunk 256
         "mamba_scan",
         [((B, 256, 8192), F32), ((B, 256, 8192), F32), ((B, 256, 16), F32),
