@@ -6,8 +6,9 @@ happens to fetch, so a kernel rewrite leaves the count valid.  FLOPs count
 multiply-adds as two.  Bytes are those each operand must cross HBM once.
 
 The kernel functions take one call's arguments and return ``(flops,
-bytes)``; the model-step functions give the useful work of a token, which
-counts real rows only and one head per row.
+bytes)``.  What a model step gives each kernel, and the FLOPs of its trunk,
+depend on the family and live in ``bench/families/<family>.py``; the
+unembedding of one token is the same for every family and stays here.
 """
 from __future__ import annotations
 
@@ -38,23 +39,35 @@ def bank_matmul(members: int, rows: int, d_in: int, d_out: int,
     return flops, nbytes
 
 
+def decode_attention(lengths, heads: int, kv_heads: int, head_dim: int,
+                     itemsize: int = BF16) -> tuple:
+    """One new token per row against its cache: for a row that attends
+    ``length`` positions, QK^T and PV over ``length`` keys per query head,
+    K and V of ``length`` positions read once, its query read and its
+    output written.  ``lengths`` holds the real rows' attended lengths; a
+    padded row or the cache's room beyond a row's length is no work."""
+    total = sum(int(n) for n in lengths)
+    flops = 4 * head_dim * heads * total
+    nbytes = itemsize * head_dim * (2 * kv_heads * total
+                                    + 2 * heads * len(lengths))
+    return flops, nbytes
+
+
+def page_gather(pages: int, page_bytes: int) -> tuple:
+    """Assembling ``pages`` pool pages of ``page_bytes`` into rows: each
+    page read once and written once; no arithmetic."""
+    return 0, 2 * pages * page_bytes
+
+
+def add(work: dict, kernel: str, fb: tuple, times: int = 1) -> None:
+    """Accumulate ``times`` calls' ``(flops, bytes)`` under ``kernel`` in
+    ``work`` (kernel -> [flops, bytes])."""
+    acc = work.setdefault(kernel, [0, 0])
+    acc[0] += fb[0] * times
+    acc[1] += fb[1] * times
+
+
 # -- model steps ----------------------------------------------------------------
-
-
-def trunk_token_flops(m, context: int) -> int:
-    """Matmul FLOPs of one token through the dense trunk; ``context`` is
-    the number of positions it attends to (itself included)."""
-    d, hd = m.d_model, m.n_heads * m.head_dim
-    kvd = m.n_kv_heads * m.head_dim
-    per_layer = (2 * d * hd + 4 * d * kvd + 2 * hd * d + 6 * d * m.d_ff
-                 + 4 * hd * context)
-    return m.n_layers * per_layer
-
-
-def sequence_trunk_flops(m, seq: int) -> int:
-    """Trunk FLOPs of one causally scored sequence of ``seq`` tokens."""
-    return seq * trunk_token_flops(m, 0) + m.n_layers * 4 * m.n_heads * m.head_dim * (
-        seq * (seq + 1) // 2)
 
 
 def head_token_flops(m) -> int:

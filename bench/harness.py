@@ -1,26 +1,32 @@
 """One run of one benchmark cell: build the merged zoo, drive its traffic
-through the program's serve path for a measured window, then check what the
+through the program's normal path for a measured window, then check what the
 window served against a plain reference.
 
-Everything that belongs to one configuration, traffic mix or per-layer
-metric lives in its own file, found by the name ``BENCHMARK.json`` gives:
+Everything that belongs to one configuration, model family, traffic mix or
+per-layer metric lives in its own file, found by the name ``BENCHMARK.json``
+or the configuration gives:
 
-    bench/configs/<config>.json    sizes, zoo, serving knobs
-    bench/traffic/<traffic>.json   parameters of the one generator
-    bench/limits/<cell>.json       the limit of each number the check compares
-    bench/metrics/<metric>.py      ``read(ctx)`` -> value or None
-    bench/peaks.json               device peaks, keyed by ``device_kind``
+    bench/configs/<config>.json      sizes, zoo, serving knobs
+    bench/families/<family>.py       the family's sizes record, weights,
+                                     program config and work counts
+    bench/references/<family>.py     its plain float32 reference
+    bench/traffic/<traffic>.json     parameters of the traffic; its ``kind``
+    bench/drivers/<kind>.py          drives that kind through the program
+    bench/limits/<cell>.json         the limit of each number the check compares
+    bench/metrics/<metric>.py        ``read(ctx)`` -> value or None
+    bench/peaks.json                 device peaks, keyed by ``device_kind``
 
 Timeline of a run (all on one host clock, ``time.perf_counter``):
 
     process start .. window start    set-up: weights, merge, engine, every
                                      shape of this cell's traffic compiled
-    window                           ``--seconds`` of open-loop traffic
-                                     through ``MergeAwareEngine.serve``
-    after the window                 HBM peak read; requests due in it finish
-                                     (their latency counts the wait); sampled
-                                     answers copied to the host; program
-                                     state freed; reference check
+                                     (the driver's warm-up)
+    window                           ``--seconds`` of the driver's traffic
+                                     through the program's normal path
+    after the window                 HBM peak read; requests open at the
+                                     close finish; sampled answers on the
+                                     host; program state freed; reference
+                                     check
 """
 from __future__ import annotations
 
@@ -34,16 +40,16 @@ import os
 import shutil
 import sys
 import tempfile
-import threading
 import time
 import types
 from collections import defaultdict
 from pathlib import Path
+from typing import Optional
 
 import jax
 import numpy as np
 
-from bench import costs, model, trace, traffic, weights
+from bench import model, trace, weights
 
 ROOT = Path(__file__).resolve().parent.parent
 BENCH = ROOT / "bench"
@@ -93,7 +99,14 @@ def resolve(spec: dict, workload: str, root: Path = ROOT) -> Cell:
     return Cell(workload, w["chips"], config, mix,
                 {m["name"]: _reader(m["name"]) for m in spec["per_layer"] if applies(m)},
                 [m["name"] for m in spec["end_to_end"] if applies(m)],
-                json.loads((root / "bench" / "limits" / f"{workload}.json").read_text()))
+                _limits(root / "bench" / "limits" / f"{workload}.json"))
+
+
+def _limits(path: Path) -> dict:
+    """A cell's limits file: compared number -> limit, and under ``why`` (left
+    out here) the reasons they were set where they are."""
+    raw = json.loads(path.read_text())
+    return {k: v for k, v in raw.items() if k != "why"}
 
 
 def peaks(device_kind: str) -> dict:
@@ -160,18 +173,6 @@ class Clock:
         return t
 
 
-def program_config(m: model.Model, program: dict):
-    """The program's own config object for model ``m``."""
-    from repro.models.transformer import DenseLMConfig
-
-    return DenseLMConfig(
-        name=program["name"], n_layers=m.n_layers, d_model=m.d_model,
-        n_heads=m.n_heads, n_kv_heads=m.n_kv_heads, head_dim=m.head_dim,
-        d_ff=m.d_ff, vocab_size=m.vocab, rotary_pct=m.rotary_pct,
-        rope_theta=m.rope_theta, qkv_bias=m.qkv_bias, dtype=m.dtype,
-        **program["fields"])
-
-
 @dataclasses.dataclass
 class Zoo:
     m: model.Model
@@ -180,6 +181,7 @@ class Zoo:
     mids: tuple
     unmerged_bytes: int
     merged_bytes: int
+    banked: bool  # the variants' heads run as one suffix bank
 
 
 def build_zoo(config: dict, seed: int, clock: Clock, buckets: tuple,
@@ -198,7 +200,7 @@ def build_zoo(config: dict, seed: int, clock: Clock, buckets: tuple,
 
     m = model.from_config(config)
     prog = config["program"]
-    pcfg = program_config(m, prog)
+    pcfg = model.family(m.family).program_config(m, prog)
     if adapter is None:
         adapter = type(get_adapter(prog["adapter"]))()
     trunk, heads = weights.zoo(m, seed)
@@ -209,7 +211,8 @@ def build_zoo(config: dict, seed: int, clock: Clock, buckets: tuple,
         mid: weights.variant_params(trunk, heads[v]) for v, mid in enumerate(mids)})
     del trunk, heads
     unmerged = store.resident_bytes()
-    prefix = adapter.split(pcfg).prefix_paths
+    split = adapter.split(pcfg)
+    prefix = split.prefix_paths
     shapes = adapter.eval_params(pcfg)
     recs = [r for mid in mids for r in adapter.records(pcfg, shapes, mid)
             if r.path in prefix]
@@ -224,7 +227,8 @@ def build_zoo(config: dict, seed: int, clock: Clock, buckets: tuple,
         programs, capacity_bytes=capacity_bytes,
         costs={"tiny-yolo": costs_for("tiny-yolo")}, buckets=buckets,
         simulate_dma=False, suffix_bank=True, clock=clock)
-    return Zoo(m, engine, store, mids, unmerged, store.resident_bytes())
+    return Zoo(m, engine, store, mids, unmerged, store.resident_bytes(),
+               split.bank_suffix is not None)
 
 
 # -- compile watch ------------------------------------------------------------
@@ -248,221 +252,134 @@ def _peak_bytes() -> int:
     return stats["peak_bytes_in_use"] if stats else 0
 
 
-# -- open loop: scoring -------------------------------------------------------
+# -- what a driver hands back ------------------------------------------------
 
 
-class _Sink(list):
-    """Stands in for ``engine.completions``: records each completion's time
-    on the run's clock and the micro-batch it rode in, and drops every
-    device result but the sampled ones, so the window holds no other
-    served logits on the chip.
-
-    The sample is ``sample`` (drawn from the seed) and one request from the
-    first micro-batch whose rows all belong to one variant, among those
-    holding a request numbered ``single_from`` or later: such batches take
-    the per-member head, the others the suffix bank.  A micro-batch's
-    completions arrive together, one after another with one time, so each
-    batch is judged when the next begins.  The sampled results stay on the
-    device until :meth:`results`, after the window."""
-
-    def __init__(self, clock: Clock, sample: set, single_from: int):
-        super().__init__()
-        self.clock, self.sample, self.single_from = clock, sample, single_from
-        self.done: dict = {}  # request index -> completion time
-        self.batches: dict = defaultdict(list)  # completion time -> [instance]
-        self.held: dict = {}  # sampled request index -> device result
-        self.single = None  # index sampled from a single-variant micro-batch
-        self._open: list = []  # (time, index, instance, result) of one batch
-
-    def append(self, c) -> None:
-        t = self.clock.mark + c.finished_s
-        if self._open and self._open[0][0] != t:
-            self._close()
-        i = c.request.meta
-        self.done[i] = t
-        self.batches[t].append(c.request.instance_id)
-        self._open.append((t, i, c.request.instance_id, c.result))
-        super().append(dataclasses.replace(c, result=None))
-
-    def _close(self) -> None:
-        batch, self._open = self._open, []
-        for _, i, _, res in batch:
-            if i in self.sample:
-                self.held[i] = res
-        if self.single is None and len({iid for _, _, iid, _ in batch}) == 1:
-            late = [(i, res) for _, i, _, res in batch if i >= self.single_from]
-            if late:
-                i, res = late[0]
-                self.single, self.held[i] = i, res
-
-    def results(self) -> dict:
-        """The sampled results, copied to the host."""
-        self._close()
-        return {i: np.asarray(r) for i, r in self.held.items()}
+@dataclasses.dataclass
+class Answer:
+    """One sampled request as the program served it: ``tokens`` (int32)
+    the positions fed to the model, ``got`` (float32, on the host) the
+    served logits of the last ``len(got)`` of them, and ``served`` the
+    tokens the program emitted at those positions (None where it emits
+    none, as in scoring)."""
+    variant: int
+    tokens: np.ndarray
+    got: np.ndarray
+    served: Optional[np.ndarray] = None
 
 
-def _warm_score(engine, mids, payload, buckets) -> None:
-    """Compile every shape the open loop can reach: each bucket's trunk,
-    bank and per-member head (``serve(warmup=...)``), then one drained
-    micro-batch of every size, single-variant and mixed, for the eager
-    gathers and slices around them."""
-    from repro.serving.executor import Request
-
-    engine.serve(horizon_s=0.0, warmup=payload, drain=True)
-    for n in range(1, buckets[-1] + 1):
-        for mixed in (False, True) if n > 1 else (False,):
-            for j in range(n):
-                engine.submit(Request(mids[j % len(mids)] if mixed else mids[0],
-                                      payload, 0.0, 1e9))
-            engine.serve(horizon_s=1e9, drain=True)
-    engine.completions.clear()
-
-
-def score_sample(mix: dict, seed: int, items: list, seconds: float) -> tuple:
-    """The seeded sample of a window's requests: ``sample_per_variant`` of
-    each variant, and the index from which the single-variant pick starts,
-    all among the requests due in the window's first half, so every sampled
-    answer is held on the device well before the window's close."""
-    rng = np.random.default_rng([seed, 2])
-    first = [it for it in items if it.due_s < seconds / 2] or items
-    by_var = defaultdict(list)
-    for it in first:
-        by_var[it.variant].append(it.index)
-    sample = set(traffic.sample_indices(rng, by_var, mix["sample_per_variant"]))
-    single_from = int(rng.integers(0, max(1, len(first) // 2)))
-    return sample, single_from
+@dataclasses.dataclass
+class Window:
+    """What one measured window gives the run: its span on the host clock,
+    what it reports, the per-layer readers' inputs and the answers the
+    check compares."""
+    t_window: float  # host clock at the window's start
+    window_s: float
+    peak_bytes: int  # the device's peak in use at the window's close
+    attempted: int
+    failed: int  # attempted requests that never completed
+    end_to_end: dict  # metric -> (value, unit), the traffic's own
+    counters: dict  # the program's counters over the window
+    work: dict  # kernel -> [flops, bytes] its calls in the window needed
+    useful_flops: float  # model FLOPs of real rows, one head per row
+    answers: list  # [Answer], the seeded sample
+    readings: dict = dataclasses.field(default_factory=dict)  # more reader inputs
+    log: dict = dataclasses.field(default_factory=dict)  # lines for stderr
+    exact: dict = dataclasses.field(default_factory=dict)  # counts whose limit is 0
 
 
-def drive_score(z: Zoo, mix: dict, seed: int, seconds: float, clock: Clock,
-                tracer) -> dict:
-    from repro.serving.executor import Request
-    from repro.serving.workload import bucket_for
-
-    eng, m = z.engine, z.m
-    items = traffic.open_schedule(mix, seed, seconds, m.variants, m.vocab)
-    sample, single_from = score_sample(mix, seed, items, seconds)
-    reqs = [Request(z.mids[it.variant], it.tokens[None, :], it.due_s,
-                    it.due_s + mix["deadline_s"], meta=it.index) for it in items]
-    eng.completions = []
-    _warm_score(eng, z.mids, reqs[0].payload, eng.buckets)
-    sink = _Sink(clock, sample, single_from)
-    eng.completions = sink
-    late = []
-
-    def generate(t0):
-        for r in reqs:
-            wait = t0 + r.arrival_s - time.perf_counter()
-            if wait > 0:
-                time.sleep(wait)
-            late.append(time.perf_counter() - t0 - r.arrival_s)
-            eng.submit(r)
-
-    # what set-up left behind is never collected again in the window: a
-    # full collection then walks only the window's own objects
-    gc.collect()
-    gc.freeze()
-    tracer.start()
-    t_win = time.perf_counter()
-    gen = threading.Thread(target=generate, args=(t_win,), daemon=True)
-    with jax.profiler.TraceAnnotation(trace.WINDOW):
-        gen.start()
-        clock.arm()
-        stats = eng.serve(horizon_s=seconds, drain=False)
-        gen.join()
-    t_end = time.perf_counter()
-    window_batches = list(sink.batches.items())
-    peak = _peak_bytes()
-    clock.arm()
-    eng.serve(horizon_s=DRAIN_S, drain=True)
-    # the device metrics are clipped to the window's span; stopping the
-    # profiler (which writes its file) after the drain keeps that wait out
-    # of the latency of the requests the window left open
-    tracer.stop()
-    gc.unfreeze()
-    results = sink.results()
-
-    lat = [(sink.done[it.index] - t_win - it.due_s) if it.index in sink.done
-           else math.inf for it in items]
-    failed = sum(1 for x in lat if math.isinf(x))
-    S = mix["prompt_tokens"]
-    work: dict = defaultdict(lambda: [0, 0])
-    useful = mixed = 0
-    for _, members in window_batches:
-        n = len(members)
-        b = bucket_for(n, eng.buckets)
-        useful += n * (costs.sequence_trunk_flops(m, S) + S * costs.head_token_flops(m))
-        _add(work, "flash_attention", costs.flash_attention(
-            b, S, m.n_heads, m.n_kv_heads, m.head_dim), m.n_layers)
-        if len(set(members)) > 1:
-            mixed += 1
-            _add(work, "bank_matmul", costs.bank_matmul(
-                m.variants, b * S, m.d_model, m.vocab))
-    return {
-        "t_window": t_win, "window_s": t_end - t_win,
-        "peak_bytes": peak, "attempted": len(items), "failed": failed,
-        "latency_s": lat,
-        "counters": {k: stats[k] for k in ("completed", "microbatches",
-                                           "suffix_dispatches", "met_sla")},
-        "late_s": late, "work": dict(work), "useful_flops": useful,
-        "mixed_batches": mixed,
-        "items": items, "results": results, "single": sink.single,
-    }
-
-
-def _add(work, kernel, fb, times=1) -> None:
-    work[kernel][0] += fb[0] * times
-    work[kernel][1] += fb[1] * times
+def driver(kind: str):
+    """The module ``bench/drivers/<kind>.py``."""
+    return importlib.import_module(f"bench.drivers.{kind}")
 
 
 # -- the check against the plain reference ------------------------------------
+
+PAD = 128  # reference batches are padded to a multiple of this many positions
 
 
 def _rms(x, axis=None):
     return np.sqrt(np.mean(np.square(x, dtype=np.float64), axis=axis))
 
 
-def check(m: model.Model, seed: int, items: list, got: dict,
+def _rows(logits, group: list) -> np.ndarray:
+    """The rows of ``logits`` (B, S, V) that line up with each answer's
+    ``got``, stacked as (positions, V)."""
+    return np.concatenate([logits[j, len(a.tokens) - len(a.got):len(a.tokens)]
+                           for j, a in enumerate(group)])
+
+
+def _gap(r, chosen, scale) -> float:
+    """Widest gap by which a chosen token's reference logit lies below the
+    reference's best at its position, in units of ``scale``."""
+    picked = r[np.arange(len(r)), chosen]
+    return float(np.max(r.max(-1) - picked) / scale)
+
+
+def check(m: model.Model, seed: int, answers: list,
           control: bool = False) -> tuple:
     """Compare what the window served with the plain float32 reference of
-    each variant's own weights, rebuilt from the seed.  Returns
-    ``(numbers, info, control_numbers)``.
+    each variant's own weights, rebuilt from the seed, run once over each
+    sampled request's positions.  Returns ``(numbers, info,
+    control_numbers)``; every number is the worst variant's.
 
     ``logit_err`` is the widest gap between a served logit and the
-    reference's, over every position and vocabulary entry of the sampled
-    requests, in units of the reference logits' RMS (per variant; the
-    worst variant counts).
+    reference's, over every compared position and vocabulary entry, in
+    units of the reference logits' RMS over those positions.  Where the
+    program emitted tokens, ``token_gap`` is the widest gap by which an
+    emitted token's reference logit lies below the reference's best at its
+    position, in the same units: greedy decoding emits the best.
 
     With ``control``, the control's answers take the program's place: the
     reference itself computed in float8 (the step below the
-    configuration's bfloat16) over the same prompts, compared with the
-    float32 reference by the same arithmetic."""
+    configuration's bfloat16) over the same positions, compared by the
+    same arithmetic; its tokens are the ones float8 puts first."""
     reference = importlib.import_module(f"bench.references.{m.family}")
     trunk, heads = weights.zoo(m, seed)
-    errs, rmse, top1, cerrs = [], [], [], []
-    for v in range(m.variants):
-        idx = sorted(i for i in got if items[i].variant == v)
-        if not idx:
-            continue
-        toks = np.stack([items[i].tokens for i in idx])
-        r = np.asarray(reference.logits(m, trunk, heads[v], toks))
-        g = np.stack([got[i] for i in idx]).astype(np.float32)
+    by_var = defaultdict(list)
+    for a in answers:
+        by_var[a.variant].append(a)
+    errs, gaps, rmse, top1, cerrs, cgaps = [], [], [], [], [], []
+    for v in sorted(by_var):
+        group = by_var[v]
+        S = -(-max(len(a.tokens) for a in group) // PAD) * PAD
+        toks = np.zeros((len(group), S), np.int32)
+        for j, a in enumerate(group):
+            toks[j, :len(a.tokens)] = a.tokens
+        r = _rows(np.asarray(reference.logits(m, trunk, heads[v], toks)), group)
+        g = np.concatenate([a.got for a in group]).astype(np.float32)
         scale = _rms(r)
         errs.append(float(np.max(np.abs(g - r)) / scale))
         rmse.append(float(_rms(g - r) / scale))
         top1.append(float(np.mean(g.argmax(-1) == r.argmax(-1))))
+        served = [a.served for a in group if a.served is not None]
+        if served:
+            gaps.append(_gap(r, np.concatenate(served), scale))
         if control:
-            c = np.asarray(reference.logits(m, trunk, heads[v], toks, "fp8"))
+            c = _rows(np.asarray(reference.logits(m, trunk, heads[v], toks, "fp8")),
+                      group)
             cerrs.append(float(np.max(np.abs(c - r)) / scale))
+            if served:
+                cgaps.append(_gap(r, c.argmax(-1), scale))
     info = {"rms_err": max(rmse, default=math.inf),
             "top1_agree": min(top1, default=0.0),
-            "sampled": len(got), "variants": len(errs)}
+            "sampled": len(answers), "variants": len(errs)}
+    numbers = {"logit_err": max(errs, default=math.inf)}
     ctl = {"logit_err": max(cerrs, default=math.inf)} if control else {}
-    return {"logit_err": max(errs, default=math.inf)}, info, ctl
+    if gaps:
+        info["served_tokens"] = sum(len(a.served) for a in answers
+                                    if a.served is not None)
+        numbers["token_gap"] = max(gaps)
+        if control:
+            ctl["token_gap"] = max(cgaps)
+    return numbers, info, ctl
 
 
 def judge(numbers: dict, limits: dict) -> tuple:
-    """``(correct, checks)``: every compared number at or under its limit,
-    and each beside it."""
+    """``(correct, checks)``: every limited number at or under its limit,
+    and each beside it.  A number the run did not produce reads as
+    infinite; a number with no limit is a fault of the cell's files."""
+    numbers = {**{k: math.inf for k in limits}, **numbers}
     checks = {k: {"value": v, "limit": limits[k]} for k, v in numbers.items()}
     return all(c["value"] <= c["limit"] for c in checks.values()), checks
 
@@ -516,55 +433,46 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
     stats0 = dev.memory_stats() or {}
     capacity = stats0.get("bytes_limit", 16 * 2 ** 30)
     cfg, mix = cell.config, cell.mix
+    drive = driver(mix["kind"]).drive
     clock = Clock()
     zoo = build_zoo(cfg, seed, clock, tuple(cfg["serving"]["score_buckets"]),
                     capacity, adapter)
     say("resident_bytes.unmerged", zoo.unmerged_bytes)
     say("resident_bytes.merged", zoo.merged_bytes)
     tracer = Tracer(traced)
-    out = drive_score(zoo, mix, seed, seconds, clock, tracer)
+    win = drive(zoo, mix, seed, seconds, clock, tracer)
     window_compiles = sum(1 for t in _COMPILES
-                          if out["t_window"] <= t <= out["t_window"] + out["window_s"])
+                          if win.t_window <= t <= win.t_window + win.window_s)
     resident = zoo.store.resident_bytes()
     m = zoo.m
     del zoo
     gc.collect()
 
-    setup_s = out["t_window"] - t_process
+    setup_s = win.t_window - t_process
     say("setup_s", setup_s)
-    say("window_s", out["window_s"])
+    say("window_s", win.window_s)
     say("window_compiles", window_compiles)
-    say("counters", out["counters"])
-    say("mixed_batches", out["mixed_batches"])
-    finite = sorted(x for x in out["latency_s"] if math.isfinite(x))
-    if finite:
-        say("latency_ms.p50_p90_p99_max", [
-            1e3 * finite[min(len(finite) - 1, int(q * len(finite)))]
-            for q in (0.5, 0.9, 0.99, 1.0)])
-    late = out["late_s"]
-    worst = int(np.argmax(late)) if late else 0
-    say("generator_late_ms.p95_max_at_s", [
-        1e3 * _p95(late) if late else 0, 1e3 * max(late, default=0),
-        out["items"][worst].due_s if late else 0])
-    e2e = {"hbm_peak_gb": (out["peak_bytes"] / 1e9, "GB"),
-           "setup_s": (setup_s, "s"),
-           "score_docs_s": (out["counters"]["completed"] / out["window_s"], "docs/s")}
+    say("counters", win.counters)
+    for k, v in win.log.items():
+        say(k, v)
+    e2e = {"hbm_peak_gb": (win.peak_bytes / 1e9, "GB"), "setup_s": (setup_s, "s"),
+           **win.end_to_end}
     metrics = {k: {"value": v, "unit": u} for k, (v, u) in e2e.items()
                if k in cell.end_to_end}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": out["peak_bytes"]}
-    result = {"correct": None, "attempted": out["attempted"],
-              "failed": out["failed"], "metrics": metrics, "device": device}
+              "count": len(jax.devices()), "memory_peak_bytes": win.peak_bytes}
+    result = {"correct": None, "attempted": win.attempted,
+              "failed": win.failed, "metrics": metrics, "device": device}
 
     if traced:
         tr = tracer.load()
         busy = trace.busy_s(tr)
         device.update(busy_s=busy, window_s=tr.window_s)
         ctx = types.SimpleNamespace(
-            model=m, window_s=out["window_s"], counters=out["counters"],
-            resident_bytes=resident, trace=tr, busy_s=busy, work=out["work"],
-            latency_s=out["latency_s"],
-            useful_flops=out["useful_flops"], peaks=peaks(dev.device_kind))
+            model=m, window_s=win.window_s, counters=win.counters,
+            resident_bytes=resident, trace=tr, busy_s=busy, work=win.work,
+            useful_flops=win.useful_flops, peaks=peaks(dev.device_kind),
+            **win.readings)
         per = {}
         for name, reader in cell.metrics.items():
             v = reader.read(ctx)
@@ -574,11 +482,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool,
         result["breakdown"] = {"device_ops": trace.top_ops(tr),
                                "idle_gaps": trace.idle_gaps(tr)}
 
-    nums, info, ctl = check(m, seed, out["items"], out["results"], control)
-    info["single_variant_sampled"] = out["single"]
+    t_check = time.perf_counter()
+    nums, info, ctl = check(m, seed, win.answers, control)
+    info["seconds"] = time.perf_counter() - t_check
     say("check", info)
-    ok, checks = judge(nums, cell.limits)
-    result["correct"] = bool(ok and out["failed"] == 0
+    limits = {**cell.limits, **dict.fromkeys(win.exact, 0)}
+    ok, checks = judge({**nums, **win.exact}, limits)
+    result["correct"] = bool(ok and win.failed == 0
                              and info["variants"] == m.variants)
     if control:
         c_ok, c_checks = judge(ctl, cell.limits)

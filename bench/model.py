@@ -2,51 +2,52 @@
 
 A configuration file under ``bench/configs/`` keeps the published model's
 own key names (``hidden_size``, ``num_hidden_layers``, ...) so that it can be
-read against its source; :func:`from_config` turns it into the one frozen
-record the weight generator, the references and the work counts share.
+read against its source, and names its ``family``.  Everything that depends
+on the family lives in ``bench/families/<family>.py`` (the sizes record it
+builds from the file, the weight generator, the program's config object, the
+FLOPs of a token and the work a step gives each kernel) and its plain
+reference in ``bench/references/<family>.py``.  Both are found by that name
+alone, so a family enters the benchmark by adding files.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
 
 import jax.numpy as jnp
 
 
 @dataclasses.dataclass(frozen=True)
 class Model:
-    family: str  # "dense": decoder-only transformer (bench/references/dense.py)
+    """The sizes every family has.  A family's record subclasses it with
+    its own widths; the record is frozen and hashable, so it can be a
+    static argument of a jitted call."""
+    family: str  # module name under bench/families/ and bench/references/
     n_layers: int
     d_model: int
     vocab: int
     variants: int
     dtype_name: str = "bfloat16"
-    n_heads: int = 0
-    n_kv_heads: int = 0
-    head_dim: int = 0
-    d_ff: int = 0
-    rotary_pct: float = 1.0
-    rope_theta: float = 1e4
-    qkv_bias: bool = False
 
     @property
     def dtype(self):
         return jnp.dtype(self.dtype_name)
 
 
+def common(cfg: dict) -> dict:
+    """The :class:`Model` fields of a configuration file, under their own
+    names, for a family's ``from_config`` to extend."""
+    return dict(family=cfg["family"], n_layers=cfg["num_hidden_layers"],
+                d_model=cfg["hidden_size"], vocab=cfg["vocab_size"],
+                variants=cfg["zoo"]["variants"], dtype_name=cfg["torch_dtype"])
+
+
+def family(name: str):
+    """The module ``bench/families/<name>.py``."""
+    return importlib.import_module(f"bench.families.{name}")
+
+
 def from_config(cfg: dict) -> Model:
-    """The :class:`Model` a configuration file describes."""
-    fam = cfg["family"]
-    common = dict(family=fam, n_layers=cfg["num_hidden_layers"],
-                  d_model=cfg["hidden_size"], vocab=cfg["vocab_size"],
-                  variants=cfg["zoo"]["variants"],
-                  dtype_name=cfg["torch_dtype"])
-    if fam == "dense":
-        heads = cfg["num_attention_heads"]
-        return Model(**common, n_heads=heads,
-                     n_kv_heads=cfg["num_key_value_heads"],
-                     head_dim=cfg["hidden_size"] // heads,
-                     d_ff=cfg["intermediate_size"],
-                     rotary_pct=cfg["partial_rotary_factor"],
-                     rope_theta=float(cfg["rope_theta"]),
-                     qkv_bias=cfg["use_qkv_bias"])
-    raise ValueError(f"unknown model family {fam!r}")
+    """The sizes record a configuration file describes, built by its
+    family."""
+    return family(cfg["family"]).from_config(cfg)

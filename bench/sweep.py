@@ -1,4 +1,4 @@
-"""Find the highest rate an open-loop cell sustains: one process builds the
+"""Find the highest rate an open-streams cell sustains: one process builds the
 cell's zoo once and offers its traffic at each rate in turn.
 
     python bench/sweep.py --workload <cell> --rates 40,80,120 --seconds 8
@@ -38,6 +38,7 @@ def main(argv=None) -> None:
     import numpy as np
 
     from bench import harness
+    from bench.drivers import open_streams
 
     cell = harness.open_chip(args.workload)
     clock = harness.Clock()
@@ -46,17 +47,18 @@ def main(argv=None) -> None:
                             tuple(cell.config["serving"]["score_buckets"]), cap)
     for rate in (float(r) for r in args.rates.split(",")):
         mix = dict(cell.mix, rate_per_s=rate)
-        out = harness.drive_score(zoo, mix, args.seed, args.seconds, clock,
-                                  harness.Tracer(False))
-        lat = np.asarray(out["latency_s"]) * 1e3
+        win = open_streams.drive(zoo, mix, args.seed, args.seconds, clock,
+                                 harness.Tracer(False))
+        lat = np.asarray(win.readings["latency_s"]) * 1e3
         fifth = max(1, len(lat) // 5)
+        batches = max(win.counters["microbatches"], 1)
         print(json.dumps({
             "rate_per_s": rate, "requests": len(lat),
-            "completed_per_s": out["counters"]["completed"] / out["window_s"],
+            "completed_per_s": win.counters["completed"] / win.window_s,
             "p50_ms": float(np.median(lat)), "p95_ms": harness._p95(lat),
             "growth_ms": float(np.median(lat[-fifth:]) - np.median(lat[:fifth])),
-            "rows_per_mb": out["counters"]["completed"] / max(out["counters"]["microbatches"], 1),
-            "mixed_share": out["mixed_batches"] / max(out["counters"]["microbatches"], 1),
+            "rows_per_mb": win.counters["completed"] / batches,
+            "mixed_share": win.readings["mixed_batches"] / batches,
         }), flush=True)
 
 

@@ -66,11 +66,12 @@ def test_sample_holds_a_single_variant_batch():
     import numpy as np
 
     from bench import harness
+    from bench.drivers import open_streams
     from repro.serving.executor import Completion, Request
 
     clock = harness.Clock()
     clock()  # marks t0
-    sink = harness._Sink(clock, sample={1, 6}, single_from=3)
+    sink = open_streams._Sink(clock, sample={1, 6}, single_from=3)
     batches = [(0.1, [(0, "a"), (1, "b")]), (0.2, [(2, "c")]),
                (0.3, [(3, "a"), (4, "d")]), (0.4, [(5, "b"), (6, "b")]),
                (0.5, [(7, "c")])]

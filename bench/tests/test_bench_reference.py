@@ -5,7 +5,7 @@ import jax
 import numpy as np
 import pytest
 
-from bench import harness, model, weights
+from bench import model, weights
 from bench.references import dense
 from bench.tests import tiny
 
@@ -13,7 +13,8 @@ from bench.tests import tiny
 def zoo(**over):
     cfg = tiny.cell(config_overrides=over).config
     m = model.from_config(cfg)
-    return m, harness.program_config(m, cfg["program"]), weights.zoo(m, 5)
+    return (m, model.family(m.family).program_config(m, cfg["program"]),
+            weights.zoo(m, 5))
 
 
 def test_reference_matches_program_forward(monkeypatch):

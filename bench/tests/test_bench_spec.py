@@ -16,14 +16,15 @@ def test_cell_resolves_by_name(cell):
     c = harness.resolve(SPEC, cell)
     assert c.config["name"] == next(w["config"] for w in SPEC["workloads"]
                                     if w["name"] == cell)
-    assert c.mix["kind"] == "open_streams"
+    assert callable(harness.driver(c.mix["kind"]).drive)
     assert c.metrics, "every cell reports a per-layer metric"
     assert "setup_s" in c.end_to_end and len(c.end_to_end) >= 2
     for name, reader in c.metrics.items():
         assert callable(reader.read) and reader.UNIT
     m = model.from_config(c.config)
     assert m.variants == 4 and m.dtype_name == "bfloat16"
-    assert set(c.limits) == {"logit_err"} and c.limits["logit_err"] > 0
+    assert "logit_err" in c.limits and set(c.limits) <= {"logit_err", "token_gap"}
+    assert all(v > 0 for v in c.limits.values())
 
 
 def test_names_units_and_moves():

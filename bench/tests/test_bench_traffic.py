@@ -1,5 +1,6 @@
 """The traffic generator: deterministic in the seed, the stated rates, phases
 and length distributions, and the same work for every seed."""
+import math
 from collections import Counter
 
 import numpy as np
@@ -86,14 +87,117 @@ def test_sample_takes_each_variant():
 
 
 def test_score_sample_lies_in_the_first_half():
-    from bench import harness
+    from bench.drivers import open_streams
 
     mx = mix("camera-128")
     items = traffic.open_schedule(mx, BIG, 10.0, 4, 1000)
-    sample, single_from = harness.score_sample(mx, BIG, items, 10.0)
+    sample, single_from = open_streams.sample(mx, BIG, items, 10.0)
     assert len(sample) == 4 * mx["sample_per_variant"]
     assert Counter(items[i].variant for i in sample) == {
         v: mx["sample_per_variant"] for v in range(4)}
     assert all(items[i].due_s < 5.0 for i in sample)
     assert 0 <= single_from < sum(1 for it in items if it.due_s < 5.0)
-    assert (sample, single_from) == harness.score_sample(mx, BIG, items, 10.0)
+    assert (sample, single_from) == open_streams.sample(mx, BIG, items, 10.0)
+
+
+def test_chat_lengths_are_log_uniform_and_the_same_for_every_seed():
+    """The lengths are log-normal about the mix's means, cut at its limits
+    (the name is older than the distribution), and every seed asks for the
+    same ones; the clients are dealt to the variants by popularity."""
+    mx = mix("chat-16")
+    a = traffic.ChatClients(mx, BIG, 4, 1000)
+    b = traffic.ChatClients(mx, BIG + 1, 4, 1000)
+    ks = range(200)
+    sizes_a = [a.sizes(c, k) for c in range(a.clients) for k in ks]
+    prompts = np.array([p for p, _ in sizes_a])
+    outputs = np.array([o for _, o in sizes_a])
+    for x, key in ((prompts, "prompt_tokens"), (outputs, "output_tokens")):
+        d = mx[key]
+        assert x.min() >= d["min"] and x.max() <= d["max"]
+        # the median of a log-normal of mean m and log-spread s is
+        # m exp(-s^2 / 2); the share cut at the upper limit is its tail
+        median = d["mean"] * np.exp(-d["sigma"] ** 2 / 2)
+        assert abs(np.median(x) / median - 1) < 0.1, (key, np.median(x))
+        z = (np.log(d["max"] + 0.5) - np.log(median)) / d["sigma"]
+        tail = 0.5 * math.erfc(z / math.sqrt(2))
+        assert abs(np.mean(x == d["max"]) - tail) < 0.02, key
+    # the seed deals the lists to other clients and draws other tokens, but
+    # asks for the same lengths
+    sizes_b = [b.sizes(c, k) for c in range(b.clients) for k in ks]
+    assert sorted(sizes_a) == sorted(sizes_b) and sizes_a != sizes_b
+    assert sorted(Counter(a.variant.tolist()).values()) == [2, 2, 4, 8]
+    fav_a, fav_b = (Counter(t.variant.tolist()).most_common(1)[0][0]
+                    for t in (a, b))
+    assert fav_a != fav_b  # the seed picks the favourite
+    r1, r2 = a.request(3, 5), traffic.ChatClients(mx, BIG, 4, 1000).request(3, 5)
+    assert np.array_equal(r1.prompt, r2.prompt) and r1.max_new == r2.max_new
+    assert (len(r1.prompt), r1.max_new) == a.sizes(3, 5)
+    assert not np.array_equal(r1.prompt, b.request(3, 5).prompt)
+
+
+def test_chat_sample_is_of_later_requests_early_in_the_window():
+    """The schedule follows the lengths, and the sample takes, per variant,
+    requests live in the window's first steps that are not their client's
+    first, the longest among them included; the same for the same seed."""
+    from bench.drivers import closed_chat
+
+    mx = mix("chat-16")
+    chats = traffic.ChatClients(mx, BIG, 4, 1000)
+    plan = closed_chat.schedule(chats, 1000)
+    for c in range(chats.clients):
+        mine = [(k, t, n) for cc, k, t, n in plan if cc == c]
+        assert [k for k, _, _ in mine] == list(range(len(mine)))
+        assert mine[0][1] == 0 and mine[-1][1] < 1000 <= mine[-1][1] + mine[-1][2]
+        for (k, t, n), (_, t2, _) in zip(mine, mine[1:]):
+            p, o = chats.sizes(c, k)
+            assert n == p + o - 1 and t2 == t + n
+    got = closed_chat.sample(mx, BIG, chats)
+    assert got == closed_chat.sample(mx, BIG, chats)
+    lo, hi = mx["warm_steps"], mx["warm_steps"] + mx["sample_steps"]
+    start = {(c, k): (t, n) for c, k, t, n in plan}
+    assert all(k > 0 and start[c, k][0] < hi and sum(start[c, k]) > lo
+               for c, k in got)
+    by_var = Counter(int(chats.variant[c]) for c, _ in got)
+    assert by_var == {v: mx["sample_per_variant"] for v in range(4)}
+    for v in range(4):
+        cands = [(c, k) for (c, k), (t, n) in start.items()
+                 if chats.variant[c] == v and k > 0 and t < hi and t + n > lo]
+        longest = max(chats.sizes(*ck)[1] for ck in cands)
+        assert longest in {chats.sizes(*ck)[1] for ck in got if ck in cands}
+
+
+def test_closed_loop_resubmits_on_retirement():
+    """Each client sends its requests in order, the next as soon as the
+    last retires and never before; none after the loop closes."""
+    from types import SimpleNamespace
+
+    from bench.drivers import closed_chat
+
+    mx = dict(mix("chat-16"), clients_per_variant=[2, 2])
+    chats = traffic.ChatClients(mx, BIG, 2, 1000)
+    sent = []
+    dec = SimpleNamespace(stats={"bank_dispatches": 0}, slots={}, completions=[],
+                          submit=lambda r: sent.append(r.meta))
+    loop = closed_chat.Loop(dec, chats, ("m0", "m1"), set())
+    for c in range(4):
+        loop.submit(c)
+    assert sent == [(0, 0), (1, 0), (2, 0), (3, 0)]
+
+    def retire(*keys):
+        for key in keys:
+            p, o = chats.sizes(*key)
+            dec.completions.append(SimpleNamespace(
+                request=SimpleNamespace(meta=key, prompt=[0] * p), tokens=[0] * o))
+        loop.on_step(dec, 0)
+
+    retire((2, 0))
+    retire()
+    retire((0, 0), (2, 1))
+    assert sent[4:] == [(2, 1), (0, 1), (2, 2)]
+    loop.open = False
+    loop.paused = 1e4  # a stop of the loop is left out of its times
+    retire((1, 0))
+    assert len(sent) == 7 and set(loop.done) == {(2, 0), (0, 0), (2, 1), (1, 0)}
+    assert loop.first[1, 0] < loop.first[2, 0] - 1e3
+    # each step's rows: the live slots (none here) and the retired ones
+    assert [len(lengths) for lengths, _, _ in loop.steps] == [1, 0, 2, 1]

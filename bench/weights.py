@@ -1,12 +1,12 @@
 """Seeded weights of a merged zoo, made on the device in one jitted call.
 
 A zoo is one shared trunk (embedding and every layer) and one private head
-(final norm and unembedding) per fine-tuned variant.  The pytrees follow the
-parameter layout the program's adapters read (``blocks/<i>/...``,
-``lm_head/w``); :func:`check_layout` holds them to the adapter's own
-``eval_params`` before anything runs.  The generator is the benchmark's, not
-the program's, so the plain references can rebuild the same weights from
-the seed without taking anything the program made.
+(final norm and unembedding) per fine-tuned variant.  The family's module
+(``bench/families/<family>.py``) makes each, in the parameter layout the
+program's adapter reads; :func:`check_layout` holds them to the adapter's
+own ``eval_params`` before anything runs.  The generator is the
+benchmark's, not the program's, so the plain references can rebuild the
+same weights from the seed without taking anything the program made.
 """
 from __future__ import annotations
 
@@ -26,58 +26,19 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF), seed >> 32)
 
 
-def _normal(key, shape, std, dtype):
+def normal(key, shape, std, dtype):
+    """Normal draws of standard deviation ``std``, made in float32 and cast
+    to ``dtype``: the one distribution every family's generator uses."""
     return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
-
-
-def _dense_trunk(m: model.Model, key) -> dict:
-    d, H, D, F, V = m.d_model, m.n_heads, m.head_dim, m.d_ff, m.vocab
-    dt = m.dtype
-    keys = iter(jax.random.split(key, 1 + 16 * m.n_layers))
-    blocks = {}
-    for i in range(m.n_layers):
-        attn = {
-            "wq": _normal(next(keys), (d, H * D), d ** -0.5, dt),
-            "wk": _normal(next(keys), (d, m.n_kv_heads * D), d ** -0.5, dt),
-            "wv": _normal(next(keys), (d, m.n_kv_heads * D), d ** -0.5, dt),
-            "wo": _normal(next(keys), (H * D, d), (H * D) ** -0.5, dt),
-        }
-        if m.qkv_bias:
-            attn["bq"] = _normal(next(keys), (H * D,), 0.02, dt)
-            attn["bk"] = _normal(next(keys), (m.n_kv_heads * D,), 0.02, dt)
-            attn["bv"] = _normal(next(keys), (m.n_kv_heads * D,), 0.02, dt)
-        blocks[str(i)] = {
-            "attn": attn,
-            "mlp": {"w_gate": _normal(next(keys), (d, F), d ** -0.5, dt),
-                    "w_up": _normal(next(keys), (d, F), d ** -0.5, dt),
-                    "w_down": _normal(next(keys), (F, d), F ** -0.5, dt)},
-            "ln1": {"scale": 1.0 + _normal(next(keys), (d,), 0.02, dt),
-                    "bias": _normal(next(keys), (d,), 0.02, dt)},
-            "ln2": {"scale": 1.0 + _normal(next(keys), (d,), 0.02, dt),
-                    "bias": _normal(next(keys), (d,), 0.02, dt)},
-        }
-    return {"embed": {"table": _normal(next(keys), (V, d), 1.0, dt)},
-            "blocks": blocks}
-
-
-def _dense_head(m: model.Model, key) -> dict:
-    k1, k2, k3 = jax.random.split(key, 3)
-    d = m.d_model
-    return {"final_norm": {"scale": 1.0 + _normal(k1, (d,), 0.02, m.dtype),
-                           "bias": _normal(k2, (d,), 0.02, m.dtype)},
-            "lm_head": {"w": _normal(k3, (d, m.vocab), d ** -0.5, m.dtype)}}
-
-
-_GENERATORS = {"dense": (_dense_trunk, _dense_head)}
 
 
 @functools.partial(jax.jit, static_argnums=(0,))
 def _zoo(m: model.Model, key):
-    trunk_fn, head_fn = _GENERATORS[m.family]
+    fam = model.family(m.family)
     k_trunk, k_heads = jax.random.split(key)
-    heads = [head_fn(m, jax.random.fold_in(k_heads, v))
+    heads = [fam.head(m, jax.random.fold_in(k_heads, v))
              for v in range(m.variants)]
-    return trunk_fn(m, k_trunk), heads
+    return fam.trunk(m, k_trunk), heads
 
 
 def zoo(m: model.Model, seed: int) -> tuple:
